@@ -17,7 +17,15 @@ from typing import Callable, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from ..operator import CTOperator
+
+
+def _sq(a) -> jnp.ndarray:
+    """``<a, a>`` as an elementwise fp32 sum.  ``jnp.vdot`` is a dot
+    product, which a TPU runs at its default (bf16-pass) matmul precision;
+    CGLS's step sizes must not drift with the volume size."""
+    return jnp.sum(a * a)
 
 
 @dataclasses.dataclass
@@ -42,22 +50,28 @@ def cgls_init(proj, geo, angles, op: Optional[CTOperator] = None,
     r = b - op.A(x)
     p = op.At(r, weight="matched")
     s = p
-    gamma = jnp.vdot(s.ravel(), s.ravel())
+    gamma = _sq(s)
+    if obs.enabled():
+        obs.event("cgls-iteration", it=0, residual=float(jnp.sqrt(_sq(r))))
     return CGLSState(op=op, b=b, x=x, r=r, p=p, gamma=gamma)
 
 
 def cgls_step(st: CGLSState) -> CGLSState:
     """One CG iteration on the normal equations."""
     q = st.op.A(st.p)
-    alpha = st.gamma / (jnp.vdot(q.ravel(), q.ravel()) + 1e-30)
+    alpha = st.gamma / (_sq(q) + 1e-30)
     st.x = st.x + alpha * st.p
     st.r = st.r - alpha * q
     s = st.op.At(st.r, weight="matched")
-    gamma_new = jnp.vdot(s.ravel(), s.ravel())
+    gamma_new = _sq(s)
     beta = gamma_new / (st.gamma + 1e-30)
     st.gamma = gamma_new
     st.p = s + beta * st.p
     st.it += 1
+    if obs.enabled():
+        # the data residual |b - A x| per iteration, for convergence checks
+        obs.event("cgls-iteration", it=st.it,
+                  residual=float(jnp.sqrt(_sq(st.r))))
     return st
 
 

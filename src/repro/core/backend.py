@@ -297,13 +297,8 @@ class PallasBackend(KernelBackend):
 
     name = "pallas"
 
-    def __init__(self, interpret: Optional[bool] = None,
-                 slab_planes: int = 16, z_block: int = 16,
-                 angle_chunk: int = 8):
+    def __init__(self, interpret: Optional[bool] = None):
         self._interpret = interpret
-        self.slab_planes = slab_planes
-        self.z_block = z_block
-        self.angle_chunk = angle_chunk
 
     @property
     def interpret(self) -> bool:
@@ -314,9 +309,7 @@ class PallasBackend(KernelBackend):
     def _blocks(self, kind: str, geo: ConeGeometry,
                 planes: Optional[int] = None) -> Dict[str, int]:
         from repro.kernels import autotune
-        pref = self.z_block if kind == "bp" else self.slab_planes
-        return autotune.get_blocks(kind, geo, planes=planes, preferred=pref,
-                                   angle_pref=self.angle_chunk,
+        return autotune.get_blocks(kind, geo, planes=planes,
                                    interpret=self.interpret)
 
     def kernel_config(self, geo: ConeGeometry, *,
@@ -325,11 +318,11 @@ class PallasBackend(KernelBackend):
         fp = self._blocks("fp", geo)
         bm = self._blocks("bp_matched", geo)
         bp = self._blocks("bp", geo, planes=planes)
-        return {"fp.slab_planes": fp["slab_planes"],
-                "bp_matched.slab_planes": bm["slab_planes"],
-                "bp.z_block": bp["z_block"],
-                "bp.angle_chunk": bp["angle_chunk"],
-                "autotuned": bool(autotune.enabled())}
+        cfg = {f"{kind}.{k}": v for kind, blocks in
+               (("fp", fp), ("bp_matched", bm), ("bp", bp))
+               for k, v in blocks.items()}
+        return dict(cfg, autotuned=bool(autotune.enabled()),
+                    interpret=self.interpret)
 
     @staticmethod
     def _check_rotation_trick(geo: ConeGeometry) -> None:
@@ -348,9 +341,10 @@ class PallasBackend(KernelBackend):
         from repro.kernels.bp_matched import bp_matched_pallas
         from repro.kernels.fp_ray import fp_ray_pallas
         interpret = self.interpret
-        sp = self._blocks("fp", geo)["slab_planes"]
-        spb = self._blocks("bp_matched", geo)["slab_planes"]
-        key = ("pallas", "fp", geo, xdom, sp, spb, interpret)
+        fb = self._blocks("fp", geo)
+        bb = self._blocks("bp_matched", geo)
+        key = ("pallas", "fp", geo, xdom, tuple(fb.items()),
+               tuple(bb.items()), interpret)
 
         def build():
             if not xdom:
@@ -364,8 +358,8 @@ class PallasBackend(KernelBackend):
                 # A^T) gets the exact adjoint without leaving Pallas
                 @jax.custom_vjp
                 def core(s, ang, z0f):
-                    return fp_ray_pallas(s, geo, ang, slab_planes=sp,
-                                         interpret=interpret, z0=z0f)
+                    return fp_ray_pallas(s, geo, ang, interpret=interpret,
+                                         z0=z0f, **fb)
 
                 def fwd(s, ang, z0f):
                     return core(s, ang, z0f), (ang, z0f)
@@ -373,8 +367,8 @@ class PallasBackend(KernelBackend):
                 def bwd(res, ct):
                     ang, z0f = res
                     sbar = bp_matched_pallas(
-                        ct, geo, ang, slab_planes=spb, interpret=interpret,
-                        z0=z0f, z_planes=planes)
+                        ct, geo, ang, interpret=interpret, z0=z0f,
+                        z_planes=planes, **bb)
                     return sbar, jnp.zeros_like(ang), jnp.zeros_like(z0f)
                 core.defvjp(fwd, bwd)
                 return core
@@ -401,17 +395,16 @@ class PallasBackend(KernelBackend):
         from repro.kernels.bp_voxel import bp_voxel_pallas
         interpret = self.interpret
         cfg = self._blocks("bp", geo, planes=planes)
-        zb, ca = cfg["z_block"], cfg["angle_chunk"]
-        key = ("pallas", "bp", geo, planes, weight, zb, ca, interpret)
+        key = ("pallas", "bp", geo, planes, weight, tuple(cfg.items()),
+               interpret)
 
         def build():
             @jax.jit
             def f(proj, angles, z0):
                 # bp_voxel clamps + pads non-divisor chunks itself
-                return bp_voxel_pallas(proj, geo, angles, z_block=zb,
-                                       angle_chunk=ca, weight=weight,
+                return bp_voxel_pallas(proj, geo, angles, weight=weight,
                                        interpret=interpret, z_start=z0,
-                                       z_planes=planes)
+                                       z_planes=planes, **cfg)
             return f
         return _TABLE.get(key, build)
 
@@ -421,8 +414,9 @@ class PallasBackend(KernelBackend):
         replaying the ray kernel's fp32 weights (no ref vjp involved)."""
         from repro.kernels.bp_matched import bp_matched_pallas
         interpret = self.interpret
-        spb = self._blocks("bp_matched", geo)["slab_planes"]
-        key = ("pallas", "bp_matched", geo, planes, xdom, spb, interpret)
+        bb = self._blocks("bp_matched", geo)
+        key = ("pallas", "bp_matched", geo, planes, xdom, tuple(bb.items()),
+               interpret)
 
         def build():
             if not xdom:
@@ -432,8 +426,8 @@ class PallasBackend(KernelBackend):
             def f(proj_chunk, angles, z0):
                 ang = angles if xdom else angles - jnp.pi / 2.0
                 slab = bp_matched_pallas(
-                    proj_chunk, geo, ang, slab_planes=spb,
-                    interpret=interpret, z0=z0, z_planes=planes)
+                    proj_chunk, geo, ang, interpret=interpret, z0=z0,
+                    z_planes=planes, **bb)
                 if not xdom:
                     # adjoint (= inverse) of the -90 deg scene rotation
                     # the forward pass applies before the ray kernel
@@ -450,9 +444,9 @@ class PallasBackend(KernelBackend):
         mask = np.asarray(mask, bool)
         interpret = self.interpret
         nz = geo.n_voxel[0]
-        spb = self._blocks("bp_matched", geo)["slab_planes"]
-        key = ("pallas", "at_matched_mixed", geo, mask.tobytes(), spb,
-               interpret)
+        bb = self._blocks("bp_matched", geo)
+        key = ("pallas", "at_matched_mixed", geo, mask.tobytes(),
+               tuple(bb.items()), interpret)
 
         def build():
             idx_x = np.nonzero(mask)[0]

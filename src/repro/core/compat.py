@@ -1,10 +1,8 @@
-"""Version compatibility shims for the JAX API surface we use.
+"""The few JAX calls the repo makes with fixed arguments, in one place.
 
-The repo targets the modern API (``jax.shard_map``, ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``); older runtimes (<= 0.4.x) ship
-the same functionality as ``jax.experimental.shard_map`` (``check_rep``)
-and ``jax.make_mesh`` without ``axis_types``.  Everything in the repo goes
-through these two helpers so a single module owns the divergence.
+``make_mesh`` always uses Auto axis types and ``shard_map`` never checks
+varying-manual-axes; every mesh and shard_map in the repo goes through
+these two so the choice is made once.
 """
 
 from __future__ import annotations
@@ -12,33 +10,21 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(shape), tuple(names),
-                             axis_types=(AxisType.Auto,) * len(names))
-    except (ImportError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` with a psum(1) fallback for older runtimes."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a named mesh axis inside ``shard_map``."""
+    return jax.lax.axis_size(axis_name)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` falling back to the experimental module.
-
-    ``check_vma`` (new name) maps onto ``check_rep`` (old name).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` (replication checking off by default)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -270,6 +270,9 @@ def dist_forward_project(mesh: Mesh, geo: ConeGeometry,
             if obs.enabled():
                 out.block_until_ready()
         return out
+    # the per-dominance sharded FP, ``(vol, angles) -> proj`` with angles a
+    # multiple of the data axis: lowerable for a described (absent) mesh
+    call.sharded = fn_for
     return call
 
 

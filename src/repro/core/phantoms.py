@@ -88,21 +88,32 @@ def sphere_projection_analytic(geo: ConeGeometry, angles: np.ndarray,
 
 
 def shepp_logan(geo: ConeGeometry, ellipsoids: Sequence[Ellipsoid] = SHEPP_LIKE) -> np.ndarray:
-    """Rasterise the ellipsoid set onto the voxel grid (additive values)."""
-    zz, yy, xx = _world_grids(geo)
+    """Rasterise the ellipsoid set onto the voxel grid (additive values).
+
+    The ellipsoids rotate about z only, so the quadric splits into an
+    (Ny, Nx) in-plane term and an (Nz,) axial term; only the z planes the
+    ellipsoid reaches are touched.  The float64 terms are added in the
+    same order as the full 3-D expression, so the mask is unchanged.
+    """
+    z = geo.voxel_centers_1d(0)
+    y = geo.voxel_centers_1d(1)[:, None]
+    x = geo.voxel_centers_1d(2)[None, :]
     half = np.array([geo.s_voxel[2], geo.s_voxel[1], geo.s_voxel[0]]) / 2.0
     vol = np.zeros(geo.n_voxel, dtype=np.float32)
     for value, (cx, cy, cz), (ax, ay, az), phi_deg in ellipsoids:
         phi = np.deg2rad(phi_deg)
         c, s = np.cos(phi), np.sin(phi)
         # normalised coords
-        xn = xx / half[0] - cx
-        yn = yy / half[1] - cy
-        zn = zz / half[2] - cz
+        xn = x / half[0] - cx
+        yn = y / half[1] - cy
+        zn = z / half[2] - cz
         xr = c * xn + s * yn
         yr = -s * xn + c * yn
-        inside = (xr / ax) ** 2 + (yr / ay) ** 2 + (zn / az) ** 2 <= 1.0
-        vol += value * inside.astype(np.float32)
+        q_xy = (xr / ax) ** 2 + (yr / ay) ** 2        # (Ny, Nx)
+        q_z = (zn / az) ** 2                           # (Nz,)
+        k = np.nonzero(q_z <= 1.0)[0]
+        inside = q_xy[None] + q_z[k, None, None] <= 1.0
+        vol[k] += value * inside.astype(np.float32)
     return vol
 
 

@@ -53,6 +53,16 @@ class MemoryModel:
     def usable(self) -> int:
         return int(self.device_bytes * self.usable_fraction)
 
+    @classmethod
+    def from_device(cls, device=None) -> "MemoryModel":
+        """Budget of a real device: its ``memory_stats()["bytes_limit"]``
+        where the backend reports one (TPU, GPU), else the default."""
+        import jax
+        device = device or jax.devices()[0]
+        stats = device.memory_stats() or {}
+        limit = int(stats.get("bytes_limit", 0))
+        return cls(device_bytes=limit) if limit > 0 else cls()
+
 
 @dataclasses.dataclass(frozen=True)
 class ForwardPlan:

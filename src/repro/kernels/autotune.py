@@ -21,16 +21,21 @@ persists it as JSON so later processes skip the measurement:
 * Candidates are floored at the heuristic block: the tuner only ever
   *grows* blocks (fewer grid steps, bigger VMEM windows), so a tuned
   config is always >= the heuristic one and the dispatch-table key —
-  which includes the chosen blocks — stays distinct per config.
+  which includes the chosen blocks — stays distinct per config.  They
+  are also bounded by the VMEM model (:func:`vmem_bytes`), and a
+  candidate that fails to compile raises instead of being skipped.
 
-The heuristic itself carries the pad-to-divisor escape hatch: when the
-largest divisor degrades below half the preferred block (prime axes used
-to force block=1), it returns the preferred block and lets the kernels'
-pad-and-mask path absorb the non-divisibility.
+The heuristic sizes blocks against that VMEM model, so every kernel
+compiles for a TPU v5e at N=512 without tuning, and carries the
+pad-to-divisor escape hatch: when the largest divisor degrades below half
+the preferred block (prime axes used to force block=1), it returns the
+preferred block and lets the kernels' pad-and-mask path absorb the
+non-divisibility.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -176,6 +181,19 @@ def _maybe_load() -> None:
 # --------------------------------------------------------------------------
 # heuristic
 
+# Block bytes the heuristic lets a kernel's VMEM estimate reach: two thirds
+# of the scoped limit it requests (fp_ray.VMEM_LIMIT_BYTES), leaving room
+# for Mosaic's own matmul and vector temporaries.
+VMEM_BUDGET_BYTES = 64 * 2**20
+# Cap on the "step" block (the one that only sets the grid-step count):
+# the rest of the budget goes to the block that cuts HBM traffic.
+_STEP_SHARE = 8
+_MAX_ANGLE_BLOCK = 64
+# Preferred step blocks before the VMEM caps: planes per slab, angles.
+_PREF_PLANES = 16
+_PREF_ANGLES = 8
+
+
 def _divisor_at_most(n: int, cap: int) -> int:
     cap = max(1, min(cap, n))
     for c in range(cap, 0, -1):
@@ -197,33 +215,111 @@ def pick_block(n: int, preferred: int) -> int:
     return min(preferred, n)
 
 
-def heuristic_blocks(kind: str, geo, *, planes: Optional[int] = None,
-                     preferred: int = 16, angle_pref: int = 8
-                     ) -> Dict[str, int]:
+def _r8(n: int) -> int:
+    return -(-n // 8) * 8
+
+
+def vmem_bytes(kind: str, geo, cfg: Dict[str, int],
+               planes: Optional[int] = None) -> int:
+    """Estimated VMEM of one ``kind`` kernel under block config ``cfg``.
+
+    Double-buffered input/output blocks, the kernel's scratch planes, and
+    its largest vector temporaries (the tent-weight matrix and the matmul
+    result, counted three times for Mosaic's fp32 matmul passes).
+    """
     nz, ny, nx = geo.n_voxel
+    nv, nu = geo.n_detector
+    zr, vr = _r8(nz if planes is None else int(planes)), _r8(nv)
     if kind in ("fp", "bp_matched"):
-        return {"slab_planes": pick_block(nx, preferred)}
+        blocks = cfg["slab_planes"] * zr * ny + cfg["angle_block"] * vr * nu
+        scratch = zr * nu + vr * nu
+        temps = ny * nu + zr * max(nu, ny)
+    elif kind == "bp":
+        blocks = (min(cfg["y_block"], ny) * _r8(min(cfg["z_block"], zr)) * nx
+                  + cfg["angle_chunk"] * vr * nu)
+        scratch = vr * nx
+        temps = nu * nx + vr * nx
+    else:
+        raise ValueError(f"unknown autotune kind: {kind!r}")
+    return 4 * (2 * blocks + scratch + 3 * temps)
+
+
+def fits(kind: str, geo, cfg: Dict[str, int],
+         planes: Optional[int] = None) -> bool:
+    return vmem_bytes(kind, geo, cfg, planes) <= VMEM_BUDGET_BYTES
+
+
+def _grow(kind, geo, cfg, key, cap, planes) -> int:
+    """Largest ``cfg[key]`` <= ``cap`` that keeps the config in budget."""
+    best = 1
+    for v in range(2, max(1, cap) + 1):
+        if not fits(kind, geo, dict(cfg, **{key: v}), planes):
+            break
+        best = v
+    return best
+
+
+def heuristic_blocks(kind: str, geo, *, planes: Optional[int] = None
+                     ) -> Dict[str, int]:
+    """VMEM-bounded default blocks for one kernel ``kind`` on ``geo``.
+
+    Each kernel has *step* blocks, which only set how many grid steps
+    there are, and one *traffic* block, which sets how often a large
+    operand is re-read from HBM.  A step block starts from its preferred
+    size and its double buffer is capped at an eighth of
+    :data:`VMEM_BUDGET_BYTES`; the traffic block then takes what is left:
+
+    * ``fp``: planes per slab (step, divisor-or-pad :func:`pick_block`);
+      angles per block (traffic: one volume-slab read serves them all);
+    * ``bp_matched``: angles per block (step); planes per slab (traffic:
+      one projection read serves them all);
+    * ``bp``: angles per chunk (step) and the whole z range per block
+      when it fits; y rows per block (traffic).
+    """
+    nz, ny, nx = geo.n_voxel
+    nv, nu = geo.n_detector
+    zr = _r8(nz if planes is None else int(planes))
+    step_cap = VMEM_BUDGET_BYTES // _STEP_SHARE
+    per_plane = 2 * 4 * zr * ny            # one double-buffered plane
+    per_angle = 2 * 4 * _r8(nv) * nu       # one double-buffered projection
+    angles = max(1, min(_PREF_ANGLES, step_cap // per_angle))
+    if kind == "fp":
+        sp = pick_block(nx, max(1, min(_PREF_PLANES, step_cap // per_plane)))
+        ab = _grow(kind, geo, {"slab_planes": sp, "angle_block": 1},
+                   "angle_block", _MAX_ANGLE_BLOCK, planes)
+        return {"slab_planes": sp, "angle_block": ab}
+    if kind == "bp_matched":
+        sp = _grow(kind, geo, {"slab_planes": 1, "angle_block": angles},
+                   "slab_planes", nx, planes)
+        return {"slab_planes": pick_block(nx, sp), "angle_block": angles}
     if kind == "bp":
-        p = nz if planes is None else int(planes)
-        return {"z_block": pick_block(p, preferred),
-                "angle_chunk": angle_pref}
+        cfg = {"z_block": zr, "angle_chunk": angles, "y_block": 1}
+        while cfg["z_block"] > 8 and not fits(kind, geo, cfg, planes):
+            cfg["z_block"] = _r8(cfg["z_block"] // 2)
+        cfg["z_block"] = min(cfg["z_block"],
+                             nz if planes is None else int(planes))
+        yb = _grow(kind, geo, cfg, "y_block", ny, planes)
+        return dict(cfg, y_block=pick_block(ny, yb))
     raise ValueError(f"unknown autotune kind: {kind!r}")
 
 
 def _candidates(kind: str, geo, planes: Optional[int],
                 heur: Dict[str, int]) -> list:
-    """Small candidate grid, floored at the heuristic config."""
+    """Small candidate grid, floored at the heuristic config and bounded
+    by the VMEM model (a candidate that cannot fit is never measured)."""
     nz, ny, nx = geo.n_voxel
-    if kind in ("fp", "bp_matched"):
-        h = heur["slab_planes"]
-        sizes = sorted({min(nx, s) for s in (h, 2 * h, 4 * h, nx)
-                        if min(nx, s) >= h})
-        return [{"slab_planes": s} for s in sizes]
     p = nz if planes is None else int(planes)
-    hz, hc = heur["z_block"], heur["angle_chunk"]
-    zs = sorted({min(p, s) for s in (hz, 2 * hz, p) if min(p, s) >= hz})
-    cas = sorted({hc, 2 * hc})
-    return [{"z_block": z, "angle_chunk": c} for z in zs for c in cas][:8]
+    extent = {"slab_planes": nx, "angle_block": _MAX_ANGLE_BLOCK,
+              "z_block": p, "angle_chunk": _MAX_ANGLE_BLOCK, "y_block": ny}
+    axes = [[(k, s) for s in sorted({min(extent[k], m * v)
+                                     for m in (1, 2, 4)})]
+            for k, v in heur.items()]
+    out = []
+    for combo in itertools.product(*axes):
+        cfg = dict(combo)
+        if cfg == heur or fits(kind, geo, cfg, planes):
+            out.append(cfg)
+    return out[:8]
 
 
 # --------------------------------------------------------------------------
@@ -250,27 +346,29 @@ def _measure(kind: str, geo, planes: Optional[int], cfg: Dict[str, int],
         vol = jnp.asarray(rng.standard_normal((p, ny, nx)), jnp.float32)
 
         def call():
-            return fp_ray_pallas(vol, geo, angles,
-                                 slab_planes=cfg["slab_planes"],
-                                 interpret=interpret, z0=0)
+            return fp_ray_pallas(vol, geo, angles, interpret=interpret,
+                                 z0=0, **cfg)
     elif kind == "bp_matched":
         proj = jnp.asarray(rng.standard_normal((n_ang, nv, nu)), jnp.float32)
 
         def call():
-            return bp_matched_pallas(proj, geo, angles,
-                                     slab_planes=cfg["slab_planes"],
-                                     interpret=interpret, z0=0, z_planes=p)
+            return bp_matched_pallas(proj, geo, angles, interpret=interpret,
+                                     z0=0, z_planes=p, **cfg)
     else:
         proj = jnp.asarray(rng.standard_normal((n_ang, nv, nu)), jnp.float32)
 
         def call():
-            return bp_voxel_pallas(proj, geo, angles,
-                                   z_block=cfg["z_block"],
-                                   angle_chunk=cfg["angle_chunk"],
-                                   weight="fdk", interpret=interpret,
-                                   z_start=0, z_planes=p)
+            return bp_voxel_pallas(proj, geo, angles, weight="fdk",
+                                   interpret=interpret, z_start=0,
+                                   z_planes=p, **cfg)
 
-    call().block_until_ready()          # compile + warm
+    try:
+        call().block_until_ready()      # compile + warm
+    except Exception as e:
+        # a candidate the chip's compiler refuses is a bug in the VMEM
+        # model or the kernel, never something to skip over quietly
+        raise RuntimeError(f"autotune: {kind} candidate {cfg} failed to "
+                           f"compile or run on {geo.n_voxel}") from e
     times = []
     for _ in range(max(1, repeats)):
         t0 = time.perf_counter()
@@ -280,12 +378,10 @@ def _measure(kind: str, geo, planes: Optional[int], cfg: Dict[str, int],
 
 
 def tune(kind: str, geo, *, planes: Optional[int] = None,
-         preferred: int = 16, angle_pref: int = 8, interpret: bool = True,
-         repeats: int = 2) -> Dict[str, int]:
+         interpret: bool = True, repeats: int = 2) -> Dict[str, int]:
     """Measure the candidate grid and return (and memoise) the winner."""
     global _FINGERPRINT
-    heur = heuristic_blocks(kind, geo, planes=planes, preferred=preferred,
-                            angle_pref=angle_pref)
+    heur = heuristic_blocks(kind, geo, planes=planes)
     best_cfg, best_t = dict(heur), None
     for cfg in _candidates(kind, geo, planes, heur):
         t = _measure(kind, geo, planes, cfg, interpret, repeats)
@@ -305,7 +401,6 @@ def tune(kind: str, geo, *, planes: Optional[int] = None,
 
 
 def get_blocks(kind: str, geo, *, planes: Optional[int] = None,
-               preferred: int = 16, angle_pref: int = 8,
                interpret: bool = True, repeats: int = 2) -> Dict[str, int]:
     """Block config for a kernel ``kind`` on ``geo``.
 
@@ -314,8 +409,7 @@ def get_blocks(kind: str, geo, *, planes: Optional[int] = None,
     outside the table lock (concurrent first-misses may both measure —
     idempotent, last writer wins).
     """
-    heur = heuristic_blocks(kind, geo, planes=planes, preferred=preferred,
-                            angle_pref=angle_pref)
+    heur = heuristic_blocks(kind, geo, planes=planes)
     if not enabled():
         return heur
     with _LOCK:
@@ -324,17 +418,16 @@ def get_blocks(kind: str, geo, *, planes: Optional[int] = None,
     if hit is not None:
         # floor at the heuristic so a stale/foreign cache can never pick
         # a smaller block than the safe default
-        return {k: max(int(v), heur.get(k, 1)) for k, v in hit.items()}
-    return tune(kind, geo, planes=planes, preferred=preferred,
-                angle_pref=angle_pref, interpret=interpret, repeats=repeats)
+        return dict(heur, **{k: max(int(v), heur.get(k, 1))
+                             for k, v in hit.items() if k in heur})
+    return tune(kind, geo, planes=planes, interpret=interpret,
+                repeats=repeats)
 
 
 def warm(geo, *, planes: Optional[int] = None, kinds=_KINDS,
-         preferred: int = 16, angle_pref: int = 8,
          interpret: bool = True, repeats: int = 2
          ) -> Dict[str, Dict[str, int]]:
     """Pre-bake tuned entries for every ``kind`` on ``geo``."""
-    return {k: get_blocks(k, geo, planes=planes, preferred=preferred,
-                          angle_pref=angle_pref, interpret=interpret,
+    return {k: get_blocks(k, geo, planes=planes, interpret=interpret,
                           repeats=repeats)
             for k in kinds}

@@ -1,29 +1,30 @@
 """Pallas TPU kernel: exact transpose of the Joseph slab forward projector.
 
 ``fp_ray.py`` forward-projects by marching x planes and, per plane, doing a
-two-tap y gather followed by a two-tap z gather.  A linear gather's transpose
-is a scatter-add with the *same* indices and weights, so this kernel replays
-the identical index/weight arithmetic as ``_fp_kernel`` — bit-for-bit the
-same ``s_par`` / ``fj`` / ``fk`` / boundary masks / ``seg`` expressions — and
-turns the two gathers into two scatter-adds:
+y interpolation (an MXU matmul with the tent-weight matrix ``Wy``) followed
+by a banded z interpolation over 8-row detector tiles.  A linear map's
+transpose reuses the *same* weights with the data movement reversed, so
+this kernel calls the same helpers (:func:`~repro.kernels.fp_ray.ray_frame`,
+``ray_plane``, ``ray_fk``, ``chunk_window``) and transposes the two steps:
 
-* z gather ``take_along_axis(colz, k, axis=0)``  ->  ``.at[k, u].add(...)``
-* y gather ``take(plane, j, axis=1)``            ->  ``.at[:, j].add(...)``
+* z gather :func:`~repro.kernels.fp_ray.gather_rows`  ->
+  :func:`~repro.kernels.fp_ray.scatter_rows` (bit-identical weights);
+* y matmul ``plane @ Wy``  ->  ``colz_bar @ Wy^T``.
 
-Because every weight is recomputed from the same fp32 expressions, the pair
-satisfies ⟨Ax, y⟩ = ⟨x, Aᵀy⟩ to fp32 summation tolerance: exactly what CGLS
-and FISTA need for their convergence guarantees (TIGRE paper SS2.2 — the
-matched "Aᵀ" pair, as opposed to the filtered/voxel-driven BP).
+Because every weight comes from the same fp32 expressions, the pair
+satisfies <Ax, y> = <x, A^T y> to fp32 summation tolerance: exactly what
+CGLS and FISTA need for their convergence guarantees (TIGRE paper SS2.2 --
+the matched "A^T" pair, as opposed to the filtered/voxel-driven BP).
 
-Grid is ``(slab, angle)`` with the angle dimension innermost: each marching
-slab of the output volume accumulates scattered contributions from every
-angle while the Pallas pipeline double-buffers the next projection's
-HBM->VMEM DMA — the mirror image of the FP kernel's (angle, slab) order.
+Grid is ``(slab, angle_block)`` with the angle dimension innermost: each
+marching slab of the output volume stays resident and accumulates every
+angle while the Pallas pipeline double-buffers the next projection block's
+HBM->VMEM DMA -- the mirror image of the FP kernel's order.
 
-Like ``fp_ray_pallas``, the wrapper pads the marching axis to a multiple of
-``slab_planes`` (padded planes are computed then dropped: the exact
-transpose of FP's pad-with-zero-planes), so any block size ``<= Nx`` is
-legal — which is what lets the autotuner explore non-divisor candidates.
+Like ``fp_ray_pallas``, the wrapper pads the marching axis, the z rows,
+the detector rows and the angle count (padded outputs are dropped, padded
+inputs are zero: the exact transpose of the FP's padding), so any block
+size is legal.
 """
 
 from __future__ import annotations
@@ -32,103 +33,70 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.geometry import ConeGeometry
 
-from .fp_ray import angle_constants
+from .fp_ray import (HIGHEST, ROWS, balanced_block, chunk_window,
+                     compiler_params, padded_angle_constants, plane_centers,
+                     ray_fk, ray_frame, ray_plane, ray_rows, ray_seg,
+                     round_up, scatter_rows)
 
 
-def _bp_matched_kernel(consts_ref, xc_ref, z0_ref, proj_ref, out_ref, *,
-                       geo: ConeGeometry, px: int, nz_slab: int):
-    """One (slab, angle) grid step: scatter one projection into Px planes.
-
-    The index math below is a line-for-line copy of ``_fp_kernel``'s; only
-    the data movement is transposed (gather -> scatter-add).  Keep the two
-    in sync: any divergence breaks the adjoint identity.
+def _bp_matched_kernel(c_ref, xc_ref, z0_ref, proj_ref, out_ref, colz_ref,
+                       gseg_ref, *, geo: ConeGeometry, px: int, ab: int):
+    """One (slab, angle_block) grid step: ``ab`` projections into ``px``
+    planes, replaying ``_fp_kernel``'s weights with the data flow reversed.
     """
-    a_idx = pl.program_id(1)
-    nz, ny, nx = geo.n_voxel
-    nv, nu = geo.n_detector
-    dz, dy, dx = geo.d_voxel
-    dv, du = geo.d_detector
-    offz, offy, offx = geo.off_origin
-    offv, offu = geo.off_detector
-    z0 = z0_ref[0, 0]
+    s_idx = pl.program_id(0)
+    a_first = pl.program_id(1) * ab
+    z0 = z0_ref[0]
+    n_kc = colz_ref.shape[0] // ROWS
+    n_vt = gseg_ref.shape[0] // ROWS
 
-    c = consts_ref[0]
-    sx, sy, sz = c[0], c[1], c[2]
-    dcx, dcy = c[3], c[4]
-    eux, euy = c[5], c[6]
-
-    u = (jnp.arange(nu, dtype=jnp.float32) - (nu - 1) / 2.0) * du + offu
-    v = (jnp.arange(nv, dtype=jnp.float32) - (nv - 1) / 2.0) * dv + offv
-    d_x = dcx + u * eux - sx                       # (Nu,)
-    d_y = dcy + u * euy - sy                       # (Nu,)
-    d_z = v - sz                                   # (Nv,)
-    norm = jnp.sqrt(d_x[None, :] ** 2 + d_y[None, :] ** 2
-                    + d_z[:, None] ** 2)
-    seg = norm / jnp.maximum(jnp.abs(d_x)[None, :], 1e-9) * dx
-    inv_dx = 1.0 / jnp.where(jnp.abs(d_x) < 1e-9, 1e-9, d_x)
-
-    # cotangent rays, pre-weighted by the FP's final ``acc * seg``
-    g_seg = proj_ref[0] * seg                      # (Nv, Nu)
-    uu = jnp.broadcast_to(jnp.arange(nu, dtype=jnp.int32)[None, :],
-                          (nv, nu))
-
-    def plane_body(p, out_acc):
-        x = xc_ref[0, p]
-        s_par = (x - sx) * inv_dx                  # (Nu,)
-        yw = sy + s_par * d_y                      # (Nu,)
-        fj = (yw - offy) / dy + (ny - 1) / 2.0     # (Nu,)
-        fk = ((sz + s_par[None, :] * d_z[:, None] - offz) / dz
-              + (nz - 1) / 2.0) - z0               # (Nv, Nu), slab-local
-
-        j0 = jnp.floor(fj)
-        wj = fj - j0
-        j0i = j0.astype(jnp.int32)
-        j0c = jnp.clip(j0i, 0, ny - 1)
-        j1c = jnp.clip(j0i + 1, 0, ny - 1)
-        wy0 = jnp.where((j0i >= 0) & (j0i < ny), 1.0 - wj, 0.0)     # (Nu,)
-        wy1 = jnp.where((j0i + 1 >= 0) & (j0i + 1 < ny), wj, 0.0)
-
-        k0 = jnp.floor(fk)
-        wk = fk - k0
-        k0i = k0.astype(jnp.int32)
-        k0c = jnp.clip(k0i, 0, nz_slab - 1)
-        k1c = jnp.clip(k0i + 1, 0, nz_slab - 1)
-        wz0 = jnp.where((k0i >= 0) & (k0i < nz_slab), 1.0 - wk, 0.0)
-        wz1 = jnp.where((k0i + 1 >= 0) & (k0i + 1 < nz_slab), wk, 0.0)
-
-        w = ((s_par > 0.0) & (s_par <= 1.0)).astype(jnp.float32)[None, :]
-        g = g_seg * w                              # (Nv, Nu)
-
-        # transpose of the z gather: scatter the two taps into z columns
-        colz_bar = jnp.zeros((nz_slab, nu), jnp.float32)
-        colz_bar = colz_bar.at[k0c, uu].add(g * wz0)
-        colz_bar = colz_bar.at[k1c, uu].add(g * wz1)       # (Nz, Nu)
-
-        # transpose of the y gather: scatter u columns into y columns
-        plane_bar = jnp.zeros((nz_slab, ny), jnp.float32)
-        plane_bar = plane_bar.at[:, j0c].add(colz_bar * wy0[None, :])
-        plane_bar = plane_bar.at[:, j1c].add(colz_bar * wy1[None, :])
-
-        return out_acc.at[p].set(plane_bar)
-
-    acc = jax.lax.fori_loop(
-        0, px, plane_body, jnp.zeros((px, nz_slab, ny), jnp.float32))
-
-    @pl.when(a_idx == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        out_ref[0] = jnp.zeros_like(out_ref[0])
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0] += acc
+    def angle_body(a, carry):
+        fr = ray_frame(c_ref, a_first + a, geo)
+
+        def seg_body(t, c):
+            # cotangent rays, pre-weighted by the FP's final ``acc * seg``
+            d_z, _ = ray_rows(fr, t, geo)
+            sl = pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS)
+            gseg_ref[sl, :] = proj_ref[a, sl, :] * ray_seg(fr, d_z, geo)
+            return c
+        jax.lax.fori_loop(0, n_vt, seg_body, 0)
+
+        def plane_body(p, c):
+            s_par, valid, wy = ray_plane(fr, xc_ref[s_idx * px + p], geo)
+            colz_ref[...] = jnp.zeros_like(colz_ref)
+
+            def tile_body(t, c2):
+                d_z, row_ok = ray_rows(fr, t, geo)
+                fk = ray_fk(fr, s_par, d_z, z0, geo)
+                c_lo, c_hi = chunk_window(fk, (valid > 0.0) & row_ok, n_kc)
+                sl = pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS)
+                scatter_rows(fk, gseg_ref[sl, :] * valid, colz_ref,
+                             c_lo, c_hi)
+                return c2
+            jax.lax.fori_loop(0, n_vt, tile_body, 0)
+            # transpose of the y matmul: colz_bar (Nz, Nu) @ Wy^T (Nu, Ny)
+            out_ref[p] += jax.lax.dot_general(
+                colz_ref[...], wy, (((1,), (1,)), ((), ())),
+                precision=HIGHEST, preferred_element_type=jnp.float32)
+            return c
+        return jax.lax.fori_loop(0, px, plane_body, carry)
+
+    jax.lax.fori_loop(0, ab, angle_body, 0)
 
 
 def bp_matched_pallas(proj: jnp.ndarray, geo: ConeGeometry, angles,
                       slab_planes: int = 16, interpret: bool = True,
-                      z0=0, z_planes: int | None = None) -> jnp.ndarray:
+                      z0=0, z_planes: int | None = None,
+                      angle_block: int = 8) -> jnp.ndarray:
     """Matched (exact-adjoint) backprojection of x-dominant ``angles``.
 
     Returns the slab ``(z_planes, Ny, Nx)`` such that for any volume slab
@@ -145,37 +113,36 @@ def bp_matched_pallas(proj: jnp.ndarray, geo: ConeGeometry, angles,
     nz, ny, nx = geo.n_voxel
     nv, nu = geo.n_detector
     nz_slab = nz if z_planes is None else int(z_planes)
-    slab_planes = min(int(slab_planes), nx)
-    n_slabs = -(-nx // slab_planes)
-    nx_pad = n_slabs * slab_planes
-    n_angles = angles.shape[0] if hasattr(angles, "shape") else len(angles)
+    sp = min(int(slab_planes), nx)
+    n_slabs = -(-nx // sp)
+    nx_pad = n_slabs * sp
+    nz_rows, nv_rows = round_up(nz_slab, ROWS), round_up(nv, ROWS)
+    n_angles = jnp.asarray(angles).reshape(-1).shape[0]
+    ab, n_ab = balanced_block(n_angles, angle_block)
 
-    consts = angle_constants(geo, angles)
-    # marching-plane centres, continued past Nx for the padded tail
-    xc = np.asarray(
-        (np.arange(nx_pad) - (nx - 1) / 2.0) * geo.d_voxel[2]
-        + geo.off_origin[2], np.float32).reshape(n_slabs, slab_planes)
-    z0_arr = jnp.asarray(z0, jnp.float32).reshape(1, 1)
+    proj = jnp.pad(jnp.asarray(proj, jnp.float32),
+                   ((0, n_ab * ab - n_angles), (0, nv_rows - nv), (0, 0)))
+    consts = padded_angle_constants(geo, angles, n_ab * ab)
+    z0_arr = jnp.asarray(z0, jnp.float32).reshape(1)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
 
-    kernel = functools.partial(_bp_matched_kernel, geo=geo, px=slab_planes,
-                               nz_slab=nz_slab)
     out = pl.pallas_call(
-        kernel,
-        grid=(n_slabs, n_angles),
+        functools.partial(_bp_matched_kernel, geo=geo, px=sp, ab=ab),
+        grid=(n_slabs, n_ab),
         in_specs=[
-            pl.BlockSpec((1, 8), lambda s_, a_: (a_, 0)),
-            pl.BlockSpec((1, slab_planes), lambda s_, a_: (s_, 0)),
-            pl.BlockSpec((1, 1), lambda s_, a_: (0, 0)),
-            pl.BlockSpec((1, nv, nu), lambda s_, a_: (a_, 0, 0)),
+            smem(),
+            smem(),
+            smem(),
+            pl.BlockSpec((ab, nv_rows, nu), lambda s_, a_: (a_, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, slab_planes, nz_slab, ny),
-                               lambda s_, a_: (s_, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (n_slabs, slab_planes, nz_slab, ny), jnp.float32),
+        out_specs=pl.BlockSpec((sp, nz_rows, ny), lambda s_, a_: (s_, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nx_pad, nz_rows, ny), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((nz_rows, nu), jnp.float32),
+                        pltpu.VMEM((nv_rows, nu), jnp.float32)],
+        compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
-    )(consts, jnp.asarray(xc), z0_arr, jnp.asarray(proj, jnp.float32))
+    )(consts, plane_centers(geo, nx_pad), z0_arr, proj)
 
-    # (S, Px, Nz, Ny) -> (Nx_pad, Nz, Ny) -> drop pad -> (Nz, Ny, Nx):
-    # the exact inverse of fp_ray_pallas's input slab layout.
-    vol = out.reshape(nx_pad, nz_slab, ny)[:nx]
-    return jnp.transpose(vol, (1, 2, 0))
+    # (Nx_pad, Nz_rows, Ny) -> drop pad -> (Nz, Ny, Nx): the exact inverse
+    # of fp_ray_pallas's input plane layout
+    return jnp.transpose(out[:nx, :nz_slab], (1, 2, 0))

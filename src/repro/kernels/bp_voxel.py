@@ -2,172 +2,160 @@
 
 TPU adaptation of TIGRE's backprojection kernel (paper SS2.2, Fig 4/5):
 
-* The Pallas grid iterates ``(z_block, angle_chunk)`` with the angle chunk
-  innermost; the volume block stays resident in VMEM and is *accumulated*
-  across chunks while the next chunk's projections are DMA'd in by the
-  pipeline -- exactly the paper's Fig 5 timeline (projections copied to the
-  device while the voxel-update kernel runs), realised by BlockSpec
-  pipelining instead of CUDA streams.
-* Per-voxel detector coordinates decompose as ``fu(x, y)`` and
-  ``fv = z * m(x, y) + c(x, y)``: the in-plane fields are computed once per
-  angle and reused for all ``Bz`` planes of the block.
-* The (Nv, Nu) bilinear fetch is a flat 4-tap ``jnp.take`` gather
-  (interpret-validated; Mosaic dynamic-gather on hardware).
+* The Pallas grid iterates ``(z_block, y_block, angle_chunk)`` with the
+  angle chunk innermost; the volume block stays resident in VMEM and is
+  *accumulated* across chunks while the next chunk's projections are DMA'd
+  in by the pipeline -- the paper's Fig 5 timeline (projections copied to
+  the device while the voxel-update kernel runs), realised by BlockSpec
+  pipelining instead of CUDA streams.  The block is laid out
+  ``(y, z, x)`` so one y row of it is a ``(z, x)`` tile.
+* The bilinear projection fetch is split like the FP's: for one y row the
+  detector column ``fu(x)`` does not depend on z, so the u interpolation
+  is an MXU matmul ``proj(Nv, Nu) @ Wu(Nu, Nx)``; the row ``fv`` is affine
+  in z, so the v interpolation is a banded sweep over 8-plane tiles
+  (:func:`~repro.kernels.fp_ray.gather_rows`).  Detector taps outside the
+  detector get no weight, as in the ref bilinear gather.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.geometry import ConeGeometry
-from .fp_ray import angle_constants
+
+from .fp_ray import (HIGHEST, ROWS, balanced_block, chunk_window,
+                     compiler_params, gather_rows, hat, iota_f32,
+                     padded_angle_constants, round_up)
 
 
-def _bp_kernel(consts_ref, zs_ref, proj_ref, out_ref, *, geo: ConeGeometry,
-               bz: int, ca: int, weight: str):
-    """One (z_block, angle_chunk) grid step.
+def _bp_kernel(c_ref, ys_ref, zs_ref, proj_ref, out_ref, q_ref, *,
+               geo: ConeGeometry, by: int, ca: int, zb: int, weight: str):
+    """One (z_block, y_block, angle_chunk) grid step.
 
-    ``zs_ref[0, 0]`` is the (traced) global starting plane of the output
+    ``zs_ref[0]`` is the (traced) global starting plane of the output
     slab: the kernel updates planes ``[z_start, z_start + z_planes)`` of
-    ``geo``'s volume — the full volume when ``z_planes == Nz``, one
+    ``geo``'s volume -- the full volume when ``z_planes == Nz``, one
     streamed axial slab otherwise (the angle axis is additive, so chunked
     accumulation reproduces the monolithic result exactly).
     """
-    c_idx = pl.program_id(1)
-    zb_idx = pl.program_id(0)
     nz, ny, nx = geo.n_voxel
     nv, nu = geo.n_detector
     dz, dy, dx = geo.d_voxel
     dv, du = geo.d_detector
     offz, offy, offx = geo.off_origin
     offv, offu = geo.off_detector
+    n_vc = q_ref.shape[0] // ROWS
+    z_first = pl.program_id(0) * zb
+    y_first = pl.program_id(1) * by
+    a_first = pl.program_id(2) * ca
 
-    xs = (jnp.arange(nx, dtype=jnp.float32) - (nx - 1) / 2.0) * dx + offx
-    ys = (jnp.arange(ny, dtype=jnp.float32) - (ny - 1) / 2.0) * dy + offy
-    z0 = zb_idx * bz
-    zs = ((jnp.arange(bz, dtype=jnp.float32) + z0.astype(jnp.float32)
-           + zs_ref[0, 0]) - (nz - 1) / 2.0) * dz + offz
-
-    X = xs[None, :]
-    Y = ys[:, None]
-
-    def angle_body(i, acc):
-        cst = consts_ref[0, i]
-        sx, sy = cst[0], cst[1]
-        # cos/sin recovered from e_u = (-sin, cos)
-        sth, cth = -cst[5], cst[6]
-        p = X * cth + Y * sth                      # (Ny, Nx)
-        q = -X * sth + Y * cth
-        depth = geo.DSO - p
-        mag = geo.DSD / depth
-        fu = (q * mag - offu) / du + (nu - 1) / 2.0      # (Ny, Nx)
-        fv_scale = mag / dv                               # (Ny, Nx)
-        if weight == "fdk":
-            w2d = (geo.DSO / depth) ** 2
-        elif weight == "pmatched":
-            w2d = (geo.DSD / depth) ** 2 * (geo.DSO / geo.DSD)
-        else:
-            w2d = jnp.ones_like(depth)
-
-        p2d = proj_ref[0, i]                       # (Nv, Nu)
-        flat = p2d.reshape(-1)
-
-        i0 = jnp.floor(fu)
-        wu = fu - i0
-        i0i = i0.astype(jnp.int32)
-
-        def z_body(k, acc):
-            fv = zs[k] * fv_scale - (offv / dv) + (nv - 1) / 2.0  # (Ny, Nx)
-            j0 = jnp.floor(fv)
-            wv = fv - j0
-            j0i = j0.astype(jnp.int32)
-
-            def tap(jj, ii, w):
-                ok = (jj >= 0) & (jj < nv) & (ii >= 0) & (ii < nu)
-                idx = (jnp.clip(jj, 0, nv - 1) * nu
-                       + jnp.clip(ii, 0, nu - 1))
-                return jnp.where(ok, jnp.take(flat, idx) * w, 0.0)
-
-            val = (tap(j0i, i0i, (1 - wv) * (1 - wu))
-                   + tap(j0i, i0i + 1, (1 - wv) * wu)
-                   + tap(j0i + 1, i0i, wv * (1 - wu))
-                   + tap(j0i + 1, i0i + 1, wv * wu))
-            return acc.at[k].add(val * w2d)
-
-        return jax.lax.fori_loop(0, bz, z_body, acc)
-
-    acc = jax.lax.fori_loop(0, ca, angle_body,
-                            jnp.zeros((bz, ny, nx), jnp.float32))
-
-    @pl.when(c_idx == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += acc
+    xs = (iota_f32((1, nx), 1) - (nx - 1) / 2.0) * dx + offx
+    z_start = zs_ref[0]
+
+    def angle_body(i, carry):
+        # cos/sin recovered from e_u = (-sin, cos)
+        base = (a_first + i) * 8
+        sth, cth = -c_ref[base + 5], c_ref[base + 6]
+
+        def row_body(r, c):
+            y = ys_ref[y_first + r]
+            p = xs * cth + y * sth                     # (1, Nx)
+            q = -xs * sth + y * cth
+            depth = geo.DSO - p
+            mag = geo.DSD / depth
+            fu = (q * mag - offu) / du + (nu - 1) / 2.0
+            fv_scale = mag / dv
+            if weight == "fdk":
+                w2d = (geo.DSO / depth) ** 2
+            elif weight == "pmatched":
+                w2d = (geo.DSD / depth) ** 2 * (geo.DSO / geo.DSD)
+            else:
+                w2d = jnp.ones_like(depth)
+            # u interpolation of every detector row at this y row's columns
+            q_ref[...] = jnp.dot(proj_ref[i], hat(fu - iota_f32((nu, nx), 0)),
+                                 precision=HIGHEST,
+                                 preferred_element_type=jnp.float32)
+
+            def tile_body(t, c2):
+                k = iota_f32((ROWS, 1), 0, z_first + t * ROWS) + z_start
+                zs = (k - (nz - 1) / 2.0) * dz + offz
+                fv = zs * fv_scale - (offv / dv) + (nv - 1) / 2.0   # (8, Nx)
+                c_lo, c_hi = chunk_window(fv, None, n_vc)
+                sl = pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS)
+                out_ref[r, sl, :] += gather_rows(fv, q_ref, c_lo, c_hi) * w2d
+                return c2
+            return jax.lax.fori_loop(0, zb // ROWS, tile_body, c)
+        return jax.lax.fori_loop(0, by, row_body, carry)
+
+    jax.lax.fori_loop(0, ca, angle_body, 0)
 
 
 def bp_voxel_pallas(proj: jnp.ndarray, geo: ConeGeometry, angles,
                     z_block: int = 16, angle_chunk: int = 8,
                     weight: str = "fdk", interpret: bool = True,
-                    z_start=0, z_planes: int = None) -> jnp.ndarray:
+                    z_start=0, z_planes: int = None,
+                    y_block: int = 8) -> jnp.ndarray:
     """Backproject with the Pallas kernel.
 
-    VMEM working set: ``Bz * Ny * Nx`` volume block (resident, accumulated)
-    + double-buffered ``angle_chunk`` projections -- the paper's Alg 2
-    budget ("two buffers of size N_angles ... plus the image piece").
+    VMEM working set: a ``(y_block, z_block, Nx)`` volume block (resident,
+    accumulated, double-buffered) + double-buffered ``angle_chunk``
+    projections -- the paper's Alg 2 budget ("two buffers of size
+    N_angles ... plus the image piece") -- + one ``(Nv, Nx)`` scratch.
 
     ``z_start`` (traced OK) + ``z_planes`` (static) select an axial slab
-    of ``geo``'s volume (the paper's per-device image pieces) — the
+    of ``geo``'s volume (the paper's per-device image pieces) -- the
     out-of-core streaming executor accumulates angle chunks into such
     slabs.  ``angles`` may be traced (see :mod:`repro.core.backend`).
+    Every axis pads to its block: extra planes and rows are computed then
+    dropped, extra angles carry zero projections (BP is linear in the
+    data, so they add nothing).
     """
+    if weight not in ("fdk", "pmatched", "none"):
+        raise ValueError(f"unknown weight {weight!r}")
     nz, ny, nx = geo.n_voxel
     nv, nu = geo.n_detector
-    planes = nz if z_planes is None else z_planes
-    n_angles = angles.shape[0] if hasattr(angles, "shape") else len(angles)
-    # Pad-to-divisor escape hatch: prime-sized axes used to force the
-    # dispatch heuristic down to block=1 (one grid step per plane/angle).
-    # Instead, pad the z grid (extra planes computed then dropped) and the
-    # angle axis (projections zero-masked — BP is linear in the data, so
-    # zero rows contribute nothing; angles duplicate the last entry to
-    # keep the geometry table finite).  Exact for any block size.
-    z_block = min(int(z_block), planes)
-    angle_chunk = min(int(angle_chunk), n_angles)
-    n_zb = -(-planes // z_block)
-    n_ch = -(-n_angles // angle_chunk)
-    planes_pad = n_zb * z_block
-    n_ang_pad = n_ch * angle_chunk
+    planes = nz if z_planes is None else int(z_planes)
+    n_angles = jnp.asarray(angles).reshape(-1).shape[0]
+    zb = round_up(min(int(z_block), planes), ROWS)
+    n_zb = -(-planes // zb)
+    by = min(int(y_block), ny)
+    n_yb = -(-ny // by)
+    ca, n_ch = balanced_block(n_angles, angle_chunk)
+    nv_rows = round_up(nv, ROWS)
 
-    angles = jnp.asarray(angles, jnp.float32)
-    proj = jnp.asarray(proj, jnp.float32)
-    if n_ang_pad != n_angles:
-        tail = n_ang_pad - n_angles
-        angles = jnp.concatenate(
-            [angles, jnp.broadcast_to(angles[-1:], (tail,))], 0)
-        proj = jnp.concatenate(
-            [proj, jnp.zeros((tail, nv, nu), proj.dtype)], 0)
+    proj = jnp.pad(jnp.asarray(proj, jnp.float32),
+                   ((0, n_ch * ca - n_angles), (0, nv_rows - nv), (0, 0)))
+    consts = padded_angle_constants(geo, angles, n_ch * ca)
+    ys = jnp.asarray((np.arange(n_yb * by) - (ny - 1) / 2.0) * geo.d_voxel[1]
+                     + geo.off_origin[1], jnp.float32)
+    zs_arr = jnp.asarray(z_start, jnp.float32).reshape(1)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
 
-    consts = angle_constants(geo, angles).reshape(n_ch, angle_chunk, 8)
-    proj_ch = proj.reshape(n_ch, angle_chunk, nv, nu)
-    zs_arr = jnp.asarray(z_start, jnp.float32).reshape(1, 1)
-
-    kernel = functools.partial(_bp_kernel, geo=geo, bz=z_block,
-                               ca=angle_chunk, weight=weight)
-    return pl.pallas_call(
-        kernel,
-        grid=(n_zb, n_ch),
+    out = pl.pallas_call(
+        functools.partial(_bp_kernel, geo=geo, by=by, ca=ca, zb=zb,
+                          weight=weight),
+        grid=(n_zb, n_yb, n_ch),
         in_specs=[
-            pl.BlockSpec((1, angle_chunk, 8), lambda z_, c_: (c_, 0, 0)),
-            pl.BlockSpec((1, 1), lambda z_, c_: (0, 0)),
-            pl.BlockSpec((1, angle_chunk, nv, nu), lambda z_, c_: (c_, 0, 0, 0)),
+            smem(),
+            smem(),
+            smem(),
+            pl.BlockSpec((ca, nv_rows, nu), lambda z_, y_, c_: (c_, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((z_block, ny, nx), lambda z_, c_: (z_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((planes_pad, ny, nx), jnp.float32),
+        out_specs=pl.BlockSpec((by, zb, nx), lambda z_, y_, c_: (y_, z_, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_yb * by, n_zb * zb, nx),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((nv_rows, nx), jnp.float32)],
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(consts, zs_arr, proj_ch)[:planes]
+    )(consts, ys, zs_arr, proj)
+    return jnp.transpose(out[:ny, :planes], (1, 0, 2))

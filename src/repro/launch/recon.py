@@ -128,9 +128,14 @@ def _reconstruct(algname, n, n_angles, iters, mode, device_bytes,
                  verbose, snapshot_dir, pods, backend, pin_devices=False):
     geo = ConeGeometry.nice(n)
     job_backend = None if backend == "auto" else backend
-    vol, angles, proj = make_ct_dataset(geo, n_angles)
+    vol, angles, proj = make_ct_dataset(geo, n_angles, backend=backend)
     mem = (MemoryModel(device_bytes=device_bytes)
-           if device_bytes else MemoryModel())
+           if device_bytes else MemoryModel.from_device())
+    if verbose:
+        src = ("--device-bytes" if device_bytes else
+               "device" if mem != MemoryModel() else "default")
+        print(f"[recon] device memory budget {mem.device_bytes / 2**30:.2f} "
+              f"GiB (from {src})")
     t0 = time.time()
     if pods > 1:
         # multi-pod fleet (simulated host groups): the job is routed to
@@ -318,6 +323,8 @@ def main():
                          "with REPRO_AUTOTUNE_CACHE=path or pre-bake with "
                          "tools/autotune.py)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     reconstruct(args.alg, args.n, args.angles, args.iters, args.mode,
                 args.device_bytes, snapshot_dir=args.snapshot_dir,
                 pods=args.pods, backend=args.backend, trace=args.trace,

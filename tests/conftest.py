@@ -10,12 +10,6 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-# NOTE: do NOT enable JAX's persistent compilation cache here
-# (JAX_COMPILATION_CACHE_DIR): on jax 0.4.x CPU, executables loaded from
-# the disk cache were observed to produce slightly different numerics than
-# freshly-compiled ones, breaking the exact-resume guarantee asserted by
-# tests/test_fault_tolerance.py (cold cache passes, warm cache fails).
-
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 

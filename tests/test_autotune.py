@@ -10,6 +10,7 @@ test runs the real path on a tiny geometry.
 import json
 import os
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -60,14 +61,22 @@ def test_pick_block_divisor_and_pad_fallback():
 
 
 def test_heuristic_blocks_per_kind():
-    assert autotune.heuristic_blocks("fp", GEO) == {"slab_planes": 16}
+    # small geometry: VMEM never binds, the traffic block grows to its cap
+    assert autotune.heuristic_blocks("fp", GEO) == \
+        {"slab_planes": 16, "angle_block": 64}
     assert autotune.heuristic_blocks("bp_matched", GEO) == \
-        {"slab_planes": 16}
+        {"slab_planes": 16, "angle_block": 8}
     assert autotune.heuristic_blocks("bp", GEO, planes=8) == \
-        {"z_block": 8, "angle_chunk": 8}
+        {"z_block": 8, "angle_chunk": 8, "y_block": 16}
     # prime x axis: the escape hatch keeps the preferred slab width
-    assert autotune.heuristic_blocks("fp", GEO.with_voxels((16, 16, 17))) \
-        == {"slab_planes": 16}
+    assert autotune.heuristic_blocks(
+        "fp", GEO.with_voxels((16, 16, 17)))["slab_planes"] == 16
+    # N=512: every kernel's blocks are capped by the VMEM model
+    big = ConeGeometry.nice(512)
+    for kind in ("fp", "bp_matched", "bp"):
+        cfg = autotune.heuristic_blocks(kind, big)
+        assert autotune.fits(kind, big, cfg), (kind, cfg)
+    assert autotune.heuristic_blocks("fp", big)["slab_planes"] < 16
     with pytest.raises(ValueError, match="unknown autotune kind"):
         autotune.heuristic_blocks("conv", GEO)
 

@@ -127,3 +127,17 @@ def test_paper_size_limits():
     assert 8_000 <= lims["forward"] <= 22_000
     assert 5_000 <= lims["backward"] <= 12_000
     assert lims["forward"] > lims["backward"]
+
+
+@pytest.mark.parametrize("stats, want", [
+    ({"bytes_limit": 16 * 2**30, "bytes_in_use": 0}, 16 * 2**30),
+    (None, MemoryModel().device_bytes),          # backend reports nothing
+    ({"bytes_in_use": 0}, MemoryModel().device_bytes),
+])
+def test_memory_model_from_device(stats, want):
+    """The planner's budget is the device's own limit where it reports one
+    (a TPU v5e says 16 GiB), else the paper's 11 GiB default."""
+    class Dev:
+        def memory_stats(self):
+            return stats
+    assert MemoryModel.from_device(Dev()).device_bytes == want

@@ -26,6 +26,30 @@ def test_recon_driver_streaming_out_of_core():
     assert rel_s < 0.6, rel_s
 
 
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_placement(tmp_path, monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left alone; otherwise the
+    cache goes to the fixed ``<checkout>/.jax_cache``."""
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    saved = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = enable_compile_cache(tmp_path)
+        if env_dir:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == saved
+        else:
+            assert got == str(tmp_path / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
+
+
 @pytest.mark.slow
 def test_lm_training_learns():
     """~0.4M-param LM on the synthetic pipeline: loss must drop

@@ -17,7 +17,6 @@ from typing import Callable, Optional
 import jax.numpy as jnp
 import numpy as np
 
-from ... import obs
 from ..operator import CTOperator
 
 
@@ -51,8 +50,6 @@ def cgls_init(proj, geo, angles, op: Optional[CTOperator] = None,
     p = op.At(r, weight="matched")
     s = p
     gamma = _sq(s)
-    if obs.enabled():
-        obs.event("cgls-iteration", it=0, residual=float(jnp.sqrt(_sq(r))))
     return CGLSState(op=op, b=b, x=x, r=r, p=p, gamma=gamma)
 
 
@@ -68,10 +65,6 @@ def cgls_step(st: CGLSState) -> CGLSState:
     st.gamma = gamma_new
     st.p = s + beta * st.p
     st.it += 1
-    if obs.enabled():
-        # the data residual |b - A x| per iteration, for convergence checks
-        obs.event("cgls-iteration", it=st.it,
-                  residual=float(jnp.sqrt(_sq(st.r))))
     return st
 
 

@@ -14,17 +14,20 @@ bit-identical to the one-shot path.
 
 ``ckpt_fields`` names the fields of the state dataclass that constitute
 the resumable part (iterate + recurrence scalars); everything else is
-rebuilt deterministically by ``init`` on restore.
+rebuilt deterministically by ``init`` on restore.  Each registered
+``step`` runs under an ``alg.step`` span (:mod:`repro.obs`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
+from ... import obs
 from .asd_pocs import (ASDPOCSState, asd_pocs_finalize, asd_pocs_init,
                        asd_pocs_step)
 from .cgls import CGLSState, cgls_finalize, cgls_init, cgls_step
@@ -50,6 +53,15 @@ class StepwiseAlgorithm:
     # them back on restore skips recomputing them (e.g. FISTA's L comes
     # from a 6-round power iteration -- the dominant admission cost)
     resume_params: Tuple[str, ...] = ()
+
+
+def _spanned(step: Callable[[Any], Any]) -> Callable[[Any], Any]:
+    """``step`` under an ``alg.step`` layer span."""
+    @functools.wraps(step)
+    def traced(state):
+        with obs.span("alg.step", obs.LAYER):
+            return step(state)
+    return traced
 
 
 # ---- direct (single-step) algorithms ---------------------------------------
@@ -94,28 +106,28 @@ def _sart_init(proj, geo, angles, **params):
 
 REGISTRY: Dict[str, StepwiseAlgorithm] = {
     "ossart": StepwiseAlgorithm(
-        "ossart", ossart_init, ossart_step, ossart_finalize,
+        "ossart", ossart_init, _spanned(ossart_step), ossart_finalize,
         ckpt_fields=("x", "lmbda", "it"), resume_params=("lmbda",)),
     "sirt": StepwiseAlgorithm(
-        "sirt", _sirt_init, ossart_step, ossart_finalize,
+        "sirt", _sirt_init, _spanned(ossart_step), ossart_finalize,
         ckpt_fields=("x", "lmbda", "it"), resume_params=("lmbda",)),
     "sart": StepwiseAlgorithm(
-        "sart", _sart_init, ossart_step, ossart_finalize,
+        "sart", _sart_init, _spanned(ossart_step), ossart_finalize,
         ckpt_fields=("x", "lmbda", "it"), resume_params=("lmbda",)),
     "cgls": StepwiseAlgorithm(
-        "cgls", cgls_init, cgls_step, cgls_finalize,
+        "cgls", cgls_init, _spanned(cgls_step), cgls_finalize,
         ckpt_fields=("x", "r", "p", "gamma", "it"),
         default_bp_weight="matched"),
     "fista": StepwiseAlgorithm(
-        "fista", fista_tv_init, fista_tv_step, fista_tv_finalize,
+        "fista", fista_tv_init, _spanned(fista_tv_step), fista_tv_finalize,
         ckpt_fields=("x", "y", "t", "L", "it"),
         default_bp_weight="matched", resume_params=("L",)),
     "asd_pocs": StepwiseAlgorithm(
-        "asd_pocs", asd_pocs_init, asd_pocs_step, asd_pocs_finalize,
+        "asd_pocs", asd_pocs_init, _spanned(asd_pocs_step), asd_pocs_finalize,
         ckpt_fields=("x", "lmbda", "dtvg", "dp_first", "it"),
         resume_params=("lmbda",)),
     "fdk": StepwiseAlgorithm(
-        "fdk", fdk_init, fdk_step, fdk_finalize,
+        "fdk", fdk_init, _spanned(fdk_step), fdk_finalize,
         ckpt_fields=("x", "it"), iterative=False),
 }
 REGISTRY["fista_tv"] = REGISTRY["fista"]

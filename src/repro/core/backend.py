@@ -223,6 +223,7 @@ class KernelBackend:
             nv, nu = geo.n_detector
 
             @jax.jit
+            @jax.named_scope("repro.op.fp")
             def f(vol, angles):
                 out = jnp.zeros((len(mask), nv, nu), jnp.float32)
                 if fpx is not None:
@@ -245,6 +246,7 @@ class KernelBackend:
             ref_fp = get_backend("ref").fp_mixed(geo, mask)
 
             @jax.jit
+            @jax.named_scope("repro.op.bp")
             def f(proj, angles):
                 zeros = jnp.zeros(geo.n_voxel, jnp.float32)
                 _, vjp = jax.vjp(lambda v: ref_fp(v, angles), zeros)
@@ -271,6 +273,7 @@ class RefBackend(KernelBackend):
            weight: str) -> Callable:
         def build():
             @jax.jit
+            @jax.named_scope("repro.op.bp")
             def f(proj, angles, z0):
                 return proj_mod.backproject_voxel(
                     proj, geo, angles, weight=weight, z_start=z0,
@@ -400,6 +403,7 @@ class PallasBackend(KernelBackend):
 
         def build():
             @jax.jit
+            @jax.named_scope("repro.op.bp")
             def f(proj, angles, z0):
                 # bp_voxel clamps + pads non-divisor chunks itself
                 return bp_voxel_pallas(proj, geo, angles, weight=weight,
@@ -457,6 +461,7 @@ class PallasBackend(KernelBackend):
                    if idx_y.size else None)
 
             @jax.jit
+            @jax.named_scope("repro.op.bp")
             def f(proj, angles):
                 out = jnp.zeros(geo.n_voxel, jnp.float32)
                 if bmx is not None:

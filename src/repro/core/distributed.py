@@ -44,27 +44,22 @@ from .projector import (_joseph_xdom_one_angle, _rotate_vol_90,
 
 def _traced_dist(fn, op: str, mesh: Mesh, data_axis: str, model_axis: str,
                  **extra):
-    """Wrap a jitted sharded op with a host-side compute span.
+    """Wrap a jitted sharded op with a host-side ``op.dist.group`` span.
 
     Spans cannot be opened *inside* shard_map (the body is traced code),
-    so each call gets one span carrying the shard layout; with tracing
-    enabled the wrapper blocks on the result so the span is honest
-    compute time (when disabled the raw async-dispatch fn runs —
-    zero overhead, unchanged overlap behaviour)."""
+    so each call gets one span carrying the shard layout.  The span times
+    the host's dispatch of the call and never waits for the result (device
+    time comes from the device trace); when tracing is disabled the raw
+    async-dispatch fn runs."""
     n_data = mesh.shape[data_axis]
     n_model = mesh.shape[model_axis]
 
     def traced(*args):
         if not obs.enabled():
             return fn(*args)
-        with obs.span(op, "compute", op=op, data_shards=n_data,
+        with obs.span("op.dist.group", obs.LAYER, op=op, data_shards=n_data,
                       model_shards=n_model, **extra):
-            out = fn(*args)
-            for leaf in jax.tree_util.tree_leaves(out):
-                block = getattr(leaf, "block_until_ready", None)
-                if block is not None:
-                    block()
-        return out
+            return fn(*args)
     return traced
 
 
@@ -217,6 +212,7 @@ def dist_forward_project(mesh: Mesh, geo: ConeGeometry,
     split = dominance_split and resolve(backend) != "ref"
 
     def sharded(fp_local):
+        @jax.named_scope("repro.op.fp")
         def body(vol_slab, angles_local):
             z0 = jax.lax.axis_index(model_axis) * planes
             part = fp_local(vol_slab, angles_local, z0)
@@ -267,8 +263,6 @@ def dist_forward_project(mesh: Mesh, geo: ConeGeometry,
                       bytes=int(len(angles_np)) * nv * nu * 4):
             for idx, p in parts:
                 out = out.at[jnp.asarray(idx)].set(p)
-            if obs.enabled():
-                out.block_until_ready()
         return out
     # the per-dominance sharded FP, ``(vol, angles) -> proj`` with angles a
     # multiple of the data axis: lowerable for a described (absent) mesh
@@ -302,6 +296,7 @@ def dist_backproject(mesh: Mesh, geo: ConeGeometry, weight: str = "fdk",
     planes = nz // n_model
     bp = get_backend(backend).bp(geo, planes=planes, weight=weight)
 
+    @jax.named_scope("repro.op.bp")
     def body(proj_local, angles_local):
         z0 = jax.lax.axis_index(model_axis) * planes
         slab = bp(proj_local, angles_local, z0)
@@ -344,6 +339,7 @@ def dist_backproject_matched(mesh: Mesh, geo: ConeGeometry,
     planes = nz // n_model
 
     if resolve(backend) == "ref":
+        @jax.named_scope("repro.op.bp")
         def body(proj_local, angles_local):
             z0 = jax.lax.axis_index(model_axis) * planes
             zeros = jnp.zeros((planes,) + tuple(geo.n_voxel[1:]),
@@ -369,6 +365,7 @@ def dist_backproject_matched(mesh: Mesh, geo: ConeGeometry,
     fns = {}
 
     def sharded(bm):
+        @jax.named_scope("repro.op.bp")
         def body(proj_local, angles_local):
             z0 = jax.lax.axis_index(model_axis) * planes
             slab = bm(proj_local, angles_local, z0)
@@ -407,6 +404,9 @@ def dist_backproject_matched(mesh: Mesh, geo: ConeGeometry,
         if out is None:
             out = jnp.zeros(geo.n_voxel, jnp.float32)
         return out
+    # the per-dominance sharded matched BP, ``(proj, angles) -> vol`` with
+    # angles a multiple of the data axis (as the dist FP's ``sharded``)
+    call.sharded = fn_for
     return call
 
 

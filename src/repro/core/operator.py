@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .backend import get_backend, resolve as resolve_backend
 from .geometry import ConeGeometry, dominant_axis_mask
 from .plan import ExecutionPlan, plan as plan_execution
@@ -172,6 +173,10 @@ class CTOperator:
 
     # ---- forward ----------------------------------------------------------
     def A(self, vol, angles=None):
+        with obs.span("op.A", obs.LAYER):
+            return self._forward(vol, angles)
+
+    def _forward(self, vol, angles):
         if self.mode == "stream":
             a = self.angles_np if angles is None else np.asarray(angles)
             from .streaming import stream_forward
@@ -194,6 +199,10 @@ class CTOperator:
 
     # ---- backward ---------------------------------------------------------
     def At(self, proj, angles=None, weight: Optional[str] = None):
+        with obs.span("op.At", obs.LAYER):
+            return self._back(proj, angles, weight)
+
+    def _back(self, proj, angles, weight):
         angles = self.angles if angles is None else angles
         weight = weight or self.bp_weight
         if self.mode == "stream":

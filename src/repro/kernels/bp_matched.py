@@ -141,6 +141,7 @@ def bp_matched_pallas(proj: jnp.ndarray, geo: ConeGeometry, angles,
                         pltpu.VMEM((nv_rows, nu), jnp.float32)],
         compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="bp_matched",
     )(consts, plane_centers(geo, nx_pad), z0_arr, proj)
 
     # (Nx_pad, Nz_rows, Ny) -> drop pad -> (Nz, Ny, Nx): the exact inverse

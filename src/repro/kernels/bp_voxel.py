@@ -157,5 +157,6 @@ def bp_voxel_pallas(proj: jnp.ndarray, geo: ConeGeometry, angles,
         scratch_shapes=[pltpu.VMEM((nv_rows, nx), jnp.float32)],
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
+        name="bp_voxel",
     )(consts, ys, zs_arr, proj)
     return jnp.transpose(out[:ny, :planes], (1, 0, 2))

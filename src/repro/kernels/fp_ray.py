@@ -358,5 +358,6 @@ def fp_ray_pallas(vol: jnp.ndarray, geo: ConeGeometry, angles,
                         pltpu.VMEM((nv_rows, nu), jnp.float32)],
         compiler_params=compiler_params("parallel", "arbitrary"),
         interpret=interpret,
+        name="fp_ray",
     )(consts, plane_centers(geo, nx_pad), z0_arr, vol_t)
     return out[:n_angles, :nv]

@@ -14,7 +14,7 @@ from .calibration import (CAL_EVENT_KINDS, CalibrationKey,
 from .events import FLEET_EVENT_KINDS, fleet_event, fleet_event_log
 from .http import MetricsServer, metrics_text
 from .slo import SLOTier, slo_prometheus, slo_report
-from .trace import (PHASE_CATEGORIES, InstantEvent, Span, SpanHandle,
+from .trace import (LAYER, PHASE_CATEGORIES, InstantEvent, Span, SpanHandle,
                     Tracer, begin, chrome_trace, context, enabled, end,
                     event, get_tracer, incr, prometheus_snapshot,
                     set_tracer, span, write_chrome_trace)
@@ -25,8 +25,8 @@ __all__ = [
     "memory_calibration", "MetricsServer", "metrics_text",
     "SLOTier", "slo_prometheus", "slo_report",
     "FLEET_EVENT_KINDS", "fleet_event", "fleet_event_log",
-    "PHASE_CATEGORIES", "InstantEvent", "Span", "SpanHandle", "Tracer",
-    "begin", "chrome_trace", "context", "enabled", "end", "event",
+    "LAYER", "PHASE_CATEGORIES", "InstantEvent", "Span", "SpanHandle",
+    "Tracer", "begin", "chrome_trace", "context", "enabled", "end", "event",
     "get_tracer", "incr", "prometheus_snapshot", "set_tracer", "span",
     "write_chrome_trace",
 ]

@@ -24,9 +24,16 @@ Design rules
   merges attributes into every span/event opened on that thread, which is
   how streaming-loop spans acquire their job/pod identity without
   plumbing labels through every call signature.
+* **Profiler sink.**  ``enable(profiler=True)`` also opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` for every
+  span, so the spans land on the profiler's host plane, on the device
+  trace's clock (attrs stay in the ring buffer only, so trace event names
+  are stable).  No span or event ever waits for the device: spans time
+  host work, device time comes from the device trace.
 
 Everything here is pure stdlib -- the package must stay importable
-without jax so exporters can run anywhere (CI validators, notebooks).
+without jax so exporters can run anywhere (CI validators, notebooks);
+the profiler sink imports jax when it is turned on.
 """
 
 from __future__ import annotations
@@ -41,7 +48,7 @@ from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Union
 
 __all__ = [
-    "Span", "InstantEvent", "SpanHandle", "Tracer", "get_tracer",
+    "LAYER", "Span", "InstantEvent", "SpanHandle", "Tracer", "get_tracer",
     "set_tracer", "span", "event", "begin", "end", "context", "incr",
     "enabled", "chrome_trace", "write_chrome_trace", "prometheus_snapshot",
 ]
@@ -55,6 +62,12 @@ __all__ = [
 # dominance-split dist FP (ISSUE 7).
 PHASE_CATEGORIES = ("h2d", "compute", "d2h", "compile", "plan",
                     "prefetch", "reduce")
+
+# Category of the spans that mark a layer boundary of an iteration (serve
+# claim/finish, the algorithm step, the operator calls): they nest around
+# and inside the phase spans, so they are recorded and exported but not
+# folded into the phase-seconds accounting.
+LAYER = "layer"
 
 
 def _jsonable(v: Any) -> Any:
@@ -98,12 +111,13 @@ class InstantEvent:
 
 class SpanHandle:
     """Open span returned by :meth:`Tracer.begin` (close with ``end``)."""
-    __slots__ = ("name", "cat", "t0", "thread", "attrs", "_gen")
+    __slots__ = ("name", "cat", "t0", "thread", "attrs", "_gen", "_ann")
 
     def __init__(self, name: str, cat: str, t0: float, thread: int,
-                 attrs: Dict[str, Any], gen: int):
+                 attrs: Dict[str, Any], gen: int, ann=None):
         self.name, self.cat, self.t0 = name, cat, t0
         self.thread, self.attrs, self._gen = thread, attrs, gen
+        self._ann = ann                 # open profiler annotation, if any
 
 
 class _NullSpan:
@@ -122,7 +136,7 @@ _NULL = _NullSpan()
 
 class _SpanCtx:
     """Live span context manager (only built when tracing is enabled)."""
-    __slots__ = ("_tracer", "_name", "_cat", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_attrs", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: Dict[str, Any]):
@@ -130,13 +144,16 @@ class _SpanCtx:
         self._name, self._cat, self._attrs = name, cat, attrs
 
     def __enter__(self):
+        self._ann = self._tracer._open_annotation(self._name)
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._tracer._finish_span(self._name, self._cat, self._t0,
-                                  time.monotonic(), threading.get_ident(),
-                                  self._attrs)
+        t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tracer._finish_span(self._name, self._cat, self._t0, t1,
+                                  threading.get_ident(), self._attrs)
         return False
 
 
@@ -173,6 +190,7 @@ class Tracer:
         if enabled is None:
             enabled = os.environ.get("REPRO_TRACE", "") not in ("", "0")
         self.enabled = bool(enabled)
+        self._annotation = None         # TraceAnnotation while the sink is on
         self.capacity = int(capacity)
         self._lock = threading.Lock()
         self._records: deque = deque(maxlen=self.capacity)
@@ -187,13 +205,35 @@ class Tracer:
 
     # -- lifecycle ---------------------------------------------------------
 
-    def enable(self) -> "Tracer":
+    def enable(self, profiler: bool = False) -> "Tracer":
+        """Start recording; ``profiler=True`` also writes every span to
+        the JAX profiler's trace as ``repro.<name>`` (while a trace is
+        being collected), ``False`` turns that sink off."""
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        else:
+            self._annotation = None
         self.enabled = True
         return self
 
     def disable(self) -> "Tracer":
         self.enabled = False
+        self._annotation = None
         return self
+
+    @property
+    def profiler(self) -> bool:
+        """Whether spans are also written to the JAX profiler's trace."""
+        return self._annotation is not None
+
+    def _open_annotation(self, name: str):
+        ann = self._annotation
+        if ann is None:
+            return None
+        ann = ann("repro." + name)
+        ann.__enter__()
+        return ann
 
     def clear(self) -> None:
         """Drop all records and counters (open handles become no-ops)."""
@@ -236,16 +276,25 @@ class Tracer:
               **attrs) -> Optional[SpanHandle]:
         if not self.enabled:
             return None
+        ann = self._open_annotation(name)
         return SpanHandle(name, cat or name, time.monotonic(),
                           threading.get_ident(), self._merged_attrs(attrs),
-                          self._gen)
+                          self._gen, ann)
 
     def end(self, handle: Optional[SpanHandle], **attrs) -> None:
-        if handle is None or not self.enabled or handle._gen != self._gen:
+        if handle is None:
+            return
+        t1 = time.monotonic()
+        if handle._ann is not None:
+            # closed whatever became of the tracer, so the profiler's
+            # event ends where the span does
+            handle._ann.__exit__(None, None, None)
+            handle._ann = None
+        if not self.enabled or handle._gen != self._gen:
             return
         merged = handle.attrs if not attrs else {**handle.attrs, **attrs}
-        self._finish_span(handle.name, handle.cat, handle.t0,
-                          time.monotonic(), handle.thread, merged)
+        self._finish_span(handle.name, handle.cat, handle.t0, t1,
+                          handle.thread, merged)
 
     def _finish_span(self, name: str, cat: str, t0: float, t1: float,
                      thread: int, attrs: Dict[str, Any]) -> None:
@@ -256,8 +305,10 @@ class Tracer:
             seq = next(self._seq)
             self._records.append(Span(name, cat, t0, t1, thread, seq, attrs))
             self._total_records += 1
-            self._phase[cat] = self._phase.get(cat, 0.0) + dur
             self._span_counts[cat] = self._span_counts.get(cat, 0) + 1
+            if cat == LAYER:
+                return
+            self._phase[cat] = self._phase.get(cat, 0.0) + dur
         phase = self._tls_state().phase
         phase[cat] = phase.get(cat, 0.0) + dur
 
@@ -469,9 +520,8 @@ def begin(name: str, cat: Optional[str] = None, **attrs):
 
 
 def end(handle, **attrs) -> None:
-    t = _TRACER
-    if t.enabled:
-        t.end(handle, **attrs)
+    if handle is not None:      # None: begun while tracing was off
+        _TRACER.end(handle, **attrs)
 
 
 def context(**attrs):

@@ -251,10 +251,12 @@ class JobExecutor:
                 with obs.span("step", "compute", alg=self.job.algorithm,
                               it=self.iterations_done):
                     self._state = self.alg.step(self._state)
-                    _block_on_state(self._state)
+                    with obs.span("sync", obs.LAYER):
+                        _block_on_state(self._state)
             else:
                 self._state = self.alg.step(self._state)
-                _block_on_state(self._state)
+                with obs.span("sync", obs.LAYER):
+                    _block_on_state(self._state)
         self._phase_delta = self._phase_diff(
             tracer.thread_phase_seconds(), before)
         return self.iterations_done

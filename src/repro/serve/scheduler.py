@@ -80,6 +80,7 @@ import numpy as np
 from ..checkpoint.sharded import (latest_step, manifest_target,
                                   restore_checkpoint, save_checkpoint)
 from ..core.algorithms.stepwise import get_algorithm
+from .. import obs
 from ..obs import fleet_event
 from ..core.geometry import ConeGeometry
 from ..core.plan import plan as plan_execution
@@ -799,7 +800,7 @@ class Scheduler:
         None when nothing on the slot is runnable.  The caller MUST pair
         every claim with :meth:`finish_step`.
         """
-        with self._lock:
+        with obs.span("serve.claim", obs.LAYER), self._lock:
             runnable = [r for r in self.running.values()
                         if r.slot is slot and not r.claimed
                         and not r.preempt_requested
@@ -816,7 +817,7 @@ class Scheduler:
         """Account for a completed worker step (taken *outside* the lock)
         and resolve any state transition that queued up during it:
         failure, deferred preemption, or completion."""
-        with self._lock:
+        with obs.span("serve.finish", obs.LAYER), self._lock:
             run.claimed = False
             rec = run.record
             if rec.job.job_id not in self.running:     # defensive

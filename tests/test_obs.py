@@ -446,6 +446,28 @@ def test_executor_phase_seconds_cover_step_wall_time(tracer):
     assert step_spans and all(s.attrs["device"] == 0 for s in step_spans)
 
 
+@pytest.mark.parametrize("mode,kib", [("plain", 800), ("stream", 24)])
+def test_executor_step_spans_its_wait_for_the_state(tracer, mode, kib):
+    """The executor's own wait for a step's state opens a ``sync`` layer
+    span after the algorithm's step (inside the ``step`` span in plain
+    mode), so a profiler trace can tell it from dispatch."""
+    from repro.serve.executor import JobExecutor
+    ex = JobExecutor(_job(n_iter=2), mode=mode, memory=_mem(kib))
+    ex.start()
+    tracer.clear()
+    ex.step()
+    spans = tracer.spans()
+    sync = [s for s in spans if s.name == "sync"]
+    alg = [s for s in spans if s.name == "alg.step"]
+    assert len(sync) == 1 and len(alg) == 1 and sync[0].cat == obs.LAYER
+    assert alg[0].t1 <= sync[0].t0
+    step = [s for s in spans if s.name == "step"]
+    assert len(step) == (mode == "plain")
+    for outer in step:
+        assert outer.t0 <= sync[0].t0 <= sync[0].t1 <= outer.t1
+    assert "sync" not in tracer.phase_seconds()
+
+
 def test_summary_reports_phase_seconds_and_disabled_is_empty(tracer):
     sched = Scheduler(n_devices=1, memory=_mem(800), name="s0")
     sched.submit(_job(n_iter=2))
@@ -489,3 +511,190 @@ def test_dispatch_counters_hit_and_miss(tracer):
         == before.get("dispatch_hits", 0) + 1
     assert after.get("dispatch_misses", 0) \
         == before.get("dispatch_misses", 0)
+
+
+# --------------------------------------------------------------------------
+# profiler sink: spans on the device trace's clock, never a host sync
+# --------------------------------------------------------------------------
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under a CPU ``jax.profiler`` trace; returns the host
+    events named ``repro.*`` as ``(name, start_ns, end_ns)``, by start."""
+    import glob
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_profiler_sink_writes_nested_spans(tracer, tmp_path):
+    def body():
+        tracer.enable(profiler=True)
+        with obs.span("outer", "compute", job="j1"):
+            with obs.span("inner", "h2d", slab=3):
+                time.sleep(0.002)
+            h = obs.begin("handle", "compute", device=0)
+            obs.end(h)
+        tracer.disable()
+
+    events = _profiled(tmp_path, body)
+    assert [e[0] for e in events] == ["repro.outer", "repro.inner",
+                                      "repro.handle"]
+    outer, inner, handle = events
+    for child in (inner, handle):       # nested as they were opened
+        assert outer[1] <= child[1] and child[2] <= outer[2]
+    assert inner[2] <= handle[1]
+    # attrs stay in the ring buffer, out of the trace event names
+    assert tracer.spans(name="inner")[0].attrs == {"slab": 3}
+    assert not tracer.profiler
+
+
+def test_profiler_sink_off_writes_nothing(tracer, tmp_path):
+    def body():
+        with obs.span("ring-only", "compute"):   # enabled, no sink
+            pass
+        tracer.disable()
+        with obs.span("off", "compute"):
+            pass
+        obs.end(obs.begin("off-handle"))
+
+    assert _profiled(tmp_path, body) == []
+    assert [s.name for s in tracer.spans()] == ["ring-only"]
+
+
+def test_layer_spans_stay_out_of_phase_seconds(tracer):
+    with obs.span("step", "compute"):
+        with obs.span("op.A", obs.LAYER):
+            pass
+    assert set(tracer.phase_seconds()) == {"compute"}
+    assert set(tracer.thread_phase_seconds()) == {"compute"}
+    assert [s.name for s in tracer.spans(obs.LAYER)] == ["op.A"]
+
+
+class _SyncCounter:
+    """Counts the calls by which host code waits for a device array:
+    blocking on it, fetching it, or reading it as a Python value."""
+
+    def __init__(self, monkeypatch):
+        import collections
+        import jax
+        from jax._src.array import ArrayImpl
+        self.counts = collections.Counter()
+        for name in ("block_until_ready", "__array__", "__float__",
+                     "__int__", "__bool__", "item", "tolist"):
+            monkeypatch.setattr(ArrayImpl, name,
+                                self._counted(name, getattr(ArrayImpl, name)))
+        for name in ("block_until_ready", "device_get"):
+            monkeypatch.setattr(jax, name,
+                                self._counted(name, getattr(jax, name)))
+
+    def _counted(self, name, fn):
+        def counted(*args, **kw):
+            self.counts[name] += 1
+            return fn(*args, **kw)
+        return counted
+
+    def during(self, fn):
+        self.counts.clear()
+        out = fn()
+        return out, dict(self.counts)
+
+
+@pytest.mark.parametrize("mode", ["plain", "dist"])
+def test_tracing_adds_no_host_sync_to_a_cgls_step(tracer, monkeypatch, mode):
+    """A CGLS step on the Pallas operators (interpret mode), the path the
+    chip runs, waits for the device as often with the tracer and its
+    profiler sink on as with them off; and none of its spans blocks."""
+    import dataclasses
+    import jax
+    from jax.sharding import AxisType, Mesh
+    from repro.core.algorithms.stepwise import get_algorithm
+    mesh = None
+    if mode == "dist":
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(4, 1),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    op = CTOperator(GEO, ANGLES, mode=mode, mesh=mesh, backend="pallas")
+    alg = get_algorithm("cgls")
+    tracer.disable()
+    st = alg.init(PROJ, GEO, ANGLES, op=op)
+    jax.block_until_ready(alg.step(dataclasses.replace(st)).x)   # compile
+    sync = _SyncCounter(monkeypatch)
+
+    def step():
+        return alg.step(dataclasses.replace(st))
+
+    off, counts_off = sync.during(step)
+    tracer.enable(profiler=True)
+    on, counts_on = sync.during(step)
+    tracer.disable()
+    assert counts_on == counts_off
+    for name in ("block_until_ready", "device_get", "__float__"):
+        assert counts_on.get(name, 0) == 0, counts_on
+    names = {s.name for s in tracer.spans()}
+    assert {"alg.step", "op.A", "op.At"} <= names
+    if mode == "dist":
+        assert {"op.dist.group", "reduce"} <= names
+    np.testing.assert_array_equal(np.asarray(on.x), np.asarray(off.x))
+
+
+def _op_names(fn, *args):
+    import re
+    import jax
+    hlo = jax.jit(fn).lower(*args).compiler_ir("hlo").as_hlo_module()
+    return re.findall(r'op_name="([^"]*)"', hlo.to_string())
+
+
+@pytest.mark.parametrize("mode", ["plain", "dist"])
+def test_operator_bodies_carry_their_scope_in_hlo(mode):
+    """The jitted FP and matched-BP bodies put their ops under
+    ``repro.op.fp`` / ``repro.op.bp``: the name reaches the device
+    trace's ``tf_op`` stat, which splits the operators' glue from the
+    algorithm's eager updates."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType, Mesh
+    mesh = None
+    if mode == "dist":
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(4, 1),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    op = CTOperator(GEO, ANGLES, mode=mode, mesh=mesh, backend="pallas")
+    vol = jnp.zeros(GEO.n_voxel, jnp.float32)
+    if mode == "plain":
+        proj = jnp.zeros((len(ANGLES),) + tuple(GEO.n_detector), jnp.float32)
+        fp = _op_names(op.A, vol)
+        bp = _op_names(lambda p: op.At(p, weight="matched"), proj)
+    else:
+        # one dominance group's sharded call: the dist operators' host
+        # code regroups concrete angles, so it is not traceable whole
+        angles = jnp.zeros(8, jnp.float32)
+        proj = jnp.zeros((8,) + tuple(GEO.n_detector), jnp.float32)
+        fp = _op_names(op._a.sharded(True), vol, angles)
+        bp = _op_names(op._at_matched.sharded(True), proj, angles)
+    assert any("repro.op.fp" in n for n in fp)
+    assert any("repro.op.bp" in n for n in bp)
+    assert not any("repro.op.bp" in n for n in fp)
+    assert not any("repro.op.fp" in n for n in bp)
+
+
+def test_profiler_sink_closes_a_span_ended_after_disable(tracer, tmp_path):
+    def body():
+        tracer.enable(profiler=True)
+        h = obs.begin("crossing", "compute")
+        tracer.disable()
+        obs.end(h)                      # not recorded, but closed
+
+    (ev,) = _profiled(tmp_path, body)
+    assert ev[0] == "repro.crossing"
+    assert tracer.spans() == []

@@ -168,12 +168,14 @@ class KernelBackend:
     name = "?"
 
     def kernel_config(self, geo: ConeGeometry, *,
-                      planes: Optional[int] = None) -> Dict[str, int]:
+                      planes: Optional[int] = None,
+                      angles: Optional[np.ndarray] = None) -> Dict[str, int]:
         """Block-size configuration this backend would run ``geo`` with.
 
         Empty for backends without tunable blocks; the pallas backend
-        reports the (possibly autotuned) slab/z/angle blocks — surfaced
-        in serve calibration attrs and the operator benchmarks."""
+        reports the (possibly autotuned) slab/z/angle blocks, and with
+        ``angles`` the ray kernels' mean z chunks per detector tile —
+        surfaced in serve calibration attrs and the operator benchmarks."""
         return {}
 
     # -- slab operators ------------------------------------------------------
@@ -316,14 +318,18 @@ class PallasBackend(KernelBackend):
                                    interpret=self.interpret)
 
     def kernel_config(self, geo: ConeGeometry, *,
-                      planes: Optional[int] = None) -> Dict[str, int]:
+                      planes: Optional[int] = None,
+                      angles: Optional[np.ndarray] = None) -> Dict[str, int]:
         from repro.kernels import autotune
+        from repro.kernels.fp_ray import z_chunks_per_tile
         fp = self._blocks("fp", geo)
         bm = self._blocks("bp_matched", geo)
         bp = self._blocks("bp", geo, planes=planes)
         cfg = {f"{kind}.{k}": v for kind, blocks in
                (("fp", fp), ("bp_matched", bm), ("bp", bp))
                for k, v in blocks.items()}
+        if angles is not None:
+            cfg["z_chunks_per_tile"] = z_chunks_per_tile(geo, angles)
         return dict(cfg, autotuned=bool(autotune.enabled()),
                     interpret=self.interpret)
 

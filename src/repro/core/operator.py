@@ -161,10 +161,12 @@ class CTOperator:
 
     def kernel_config(self) -> dict:
         """The backend's (possibly autotuned) block-size config for this
-        operator's geometry — empty on backends without tunable blocks.
-        Surfaced in serve init events and the operator benchmarks."""
+        operator's geometry and angles — empty on backends without
+        tunable blocks.  Surfaced in serve init events and the operator
+        benchmarks."""
         return self._backend.kernel_config(self.geo,
-                                           planes=self.geo.n_voxel[0])
+                                           planes=self.geo.n_voxel[0],
+                                           angles=self.angles_np)
 
     def _plain_fp(self, angles_np: np.ndarray):
         """Compiled forward for a concrete angle subset: the backend's

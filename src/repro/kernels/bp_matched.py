@@ -5,10 +5,13 @@ y interpolation (an MXU matmul with the tent-weight matrix ``Wy``) followed
 by a banded z interpolation over 8-row detector tiles.  A linear map's
 transpose reuses the *same* weights with the data movement reversed, so
 this kernel calls the same helpers (:func:`~repro.kernels.fp_ray.ray_frame`,
-``ray_plane``, ``ray_fk``, ``chunk_window``) and transposes the two steps:
+``ray_plane``, ``ray_fk``, and ``tile_windows``, which finds each 128-lane
+block's z window from its corner rays on the scalar core) and transposes
+the two steps:
 
-* z gather :func:`~repro.kernels.fp_ray.gather_rows`  ->
-  :func:`~repro.kernels.fp_ray.scatter_rows` (bit-identical weights);
+* z gather :func:`~repro.kernels.fp_ray.gather_blocks`  ->
+  :func:`~repro.kernels.fp_ray.scatter_blocks` (the same per-block
+  windows, bit-identical weights);
 * y matmul ``plane @ Wy``  ->  ``colz_bar @ Wy^T``.
 
 Because every weight comes from the same fp32 expressions, the pair
@@ -38,10 +41,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.geometry import ConeGeometry
 
-from .fp_ray import (HIGHEST, ROWS, balanced_block, chunk_window,
-                     compiler_params, padded_angle_constants, plane_centers,
-                     ray_fk, ray_frame, ray_plane, ray_rows, ray_seg,
-                     round_up, scatter_rows)
+from .fp_ray import (HIGHEST, ROWS, balanced_block, compiler_params,
+                     edge_spans, lane_block, padded_angle_constants,
+                     plane_centers, ray_fk, ray_frame, ray_plane, ray_rows,
+                     ray_seg, round_up, scatter_blocks, tile_windows,
+                     z_origin)
 
 
 def _bp_matched_kernel(c_ref, xc_ref, z0_ref, proj_ref, out_ref, colz_ref,
@@ -54,6 +58,7 @@ def _bp_matched_kernel(c_ref, xc_ref, z0_ref, proj_ref, out_ref, colz_ref,
     z0 = z0_ref[0]
     n_kc = colz_ref.shape[0] // ROWS
     n_vt = gseg_ref.shape[0] // ROWS
+    bw = lane_block(gseg_ref.shape[1])
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -61,26 +66,28 @@ def _bp_matched_kernel(c_ref, xc_ref, z0_ref, proj_ref, out_ref, colz_ref,
 
     def angle_body(a, carry):
         fr = ray_frame(c_ref, a_first + a, geo)
+        za = z_origin(fr.sz, z0, geo)
 
         def seg_body(t, c):
             # cotangent rays, pre-weighted by the FP's final ``acc * seg``
-            d_z, _ = ray_rows(fr, t, geo)
             sl = pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS)
-            gseg_ref[sl, :] = proj_ref[a, sl, :] * ray_seg(fr, d_z, geo)
+            gseg_ref[sl, :] = proj_ref[a, sl, :] * ray_seg(
+                fr, ray_rows(fr, t, geo), geo)
             return c
         jax.lax.fori_loop(0, n_vt, seg_body, 0)
 
         def plane_body(p, c):
-            s_par, valid, wy = ray_plane(fr, xc_ref[s_idx * px + p], geo)
+            x = xc_ref[s_idx * px + p]
+            s_par, valid, wy = ray_plane(fr, x, geo)
+            spans = edge_spans(fr, x)
             colz_ref[...] = jnp.zeros_like(colz_ref)
 
             def tile_body(t, c2):
-                d_z, row_ok = ray_rows(fr, t, geo)
-                fk = ray_fk(fr, s_par, d_z, z0, geo)
-                c_lo, c_hi = chunk_window(fk, (valid > 0.0) & row_ok, n_kc)
+                fk = ray_fk(fr, s_par, ray_rows(fr, t, geo), z0, geo)
+                wins = tile_windows(spans, za, t, fr.sz, n_kc, geo)
                 sl = pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS)
-                scatter_rows(fk, gseg_ref[sl, :] * valid, colz_ref,
-                             c_lo, c_hi)
+                scatter_blocks(fk, gseg_ref[sl, :] * valid, colz_ref, wins,
+                               bw)
                 return c2
             jax.lax.fori_loop(0, n_vt, tile_body, 0)
             # transpose of the y matmul: colz_bar (Nz, Nu) @ Wy^T (Nu, Ny)

@@ -17,10 +17,12 @@ TPU adaptation of TIGRE's texture-cached ray-driven projection kernel
 
   - y: the tap index depends on the detector column only, so the y pass is
     one MXU matmul per plane, ``plane(Nz, Ny) @ Wy(Ny, Nu)``;
-  - z: the tap index ``fk(v, u)`` is affine in the detector row, so every
-    8-row detector tile reads a narrow band of z rows.  The band is found
-    from the tile's min/max ``fk`` and swept in aligned 8-row chunks on
-    the VPU.
+  - z: the tap index ``fk(v, u)`` is affine in the detector row and
+    monotone in the column, so every 8-row detector tile reads a narrow
+    band of z rows in each 128-lane block of columns.  Each block's band
+    follows from its corner rays by scalar arithmetic
+    (:func:`tile_windows`, no vector reduction), and one loop sweeps every
+    block's band in aligned 8-row chunks on the VPU (:func:`gather_blocks`).
 
   Taps outside the grid get no weight, which is what makes partial
   projections of disjoint z slabs sum to the monolithic one exactly.
@@ -46,9 +48,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.geometry import ConeGeometry
+from repro.core.geometry import ConeGeometry, dominant_axis_mask
 
 ROWS = 8                    # sublane tile: detector rows / z rows per step
+LANES = 128                 # lane tile: detector columns per z window
 HIGHEST = jax.lax.Precision.HIGHEST
 # Scoped VMEM requested from Mosaic.  A TPU v5e core has 128 MiB of VMEM;
 # the block heuristic in repro.kernels.autotune budgets below this.
@@ -148,23 +151,126 @@ def gather_rows(f: jnp.ndarray, src_ref, c_lo, c_hi) -> jnp.ndarray:
     return jax.lax.fori_loop(c_lo, c_hi, body, jnp.zeros_like(f))
 
 
-def scatter_rows(f: jnp.ndarray, g: jnp.ndarray, dst_ref, c_lo, c_hi):
-    """Transpose of :func:`gather_rows`: ``dst[k] += sum_i hat(f[i]-k) g[i]``.
+def z_window(a, s_a, s_b, d0, d1, n_kc: int, xp=jnp):
+    """Aligned 8-row chunks ``[c_lo, c_lo + n)`` holding every tap of one
+    lane block of a detector tile, from scalars in chunks (rows / 8).
 
-    The weights are bit-identical to :func:`gather_rows`' (same
+    A ray of the tile reads z row ``fk = 8 * (a + s * d)`` (:func:`ray_fk`):
+    ``s`` is its ray parameter at the plane, ``d`` its row's z slope
+    (:func:`z_slope`).  ``s_a``, ``s_b`` are the parameters of the block's
+    edge columns, clipped to the valid ray range ``[0, 1]`` (``s`` is
+    monotone in the column: the fan stays below 45 deg of the marching
+    axis), and ``d0 <= d1`` the slopes of the tile's first and last row.
+    ``fk`` is bilinear in ``(s, d)`` and ``s >= 0``, so its range is spanned
+    by these corners.  Taps reach row ``floor(fk) + 1``; one row of margin
+    on each side absorbs rounding between this and the per-ray expression.
+    Scalars in the kernels, arrays on the host (``xp=np``): one rule for
+    both.
+    """
+    lo = (a - 1.0 / ROWS) + xp.minimum(s_a * d0, s_b * d0)
+    hi = (a + (ROWS + 2.0) / ROWS) + xp.maximum(s_a * d1, s_b * d1)
+    c_lo = xp.clip(lo, 0.0, n_kc).astype(xp.int32)
+    c_hi = xp.clip(hi, 0.0, n_kc + (ROWS - 1.0) / ROWS).astype(xp.int32)
+    return c_lo, c_hi - c_lo
+
+
+def z_origin(sz, z0, geo: ConeGeometry):
+    """``a`` of :func:`z_window`: the slab-local z of the source, in
+    chunks."""
+    nz, dz, offz = geo.n_voxel[0], geo.d_voxel[0], geo.off_origin[0]
+    return ((sz - offz) * (1.0 / dz) + (nz - 1) / 2.0 - z0) * (1.0 / ROWS)
+
+
+def z_slope(iv, sz, geo: ConeGeometry):
+    """``d`` of :func:`z_window`: z chunks per unit ray parameter of
+    detector row ``iv``."""
+    nv, dv, offv = geo.n_detector[0], geo.d_detector[0], geo.off_detector[0]
+    per_row = 1.0 / (geo.d_voxel[0] * ROWS)
+    return iv * (dv * per_row) + (offv - sz - (nv - 1) / 2.0 * dv) * per_row
+
+
+def lane_block(nu: int) -> int:
+    """Detector columns that share one z window: a 128-lane block where
+    such blocks tile the width, else the whole width."""
+    return LANES if nu % LANES == 0 else nu
+
+
+def block_starts(windows, n_kc: int):
+    """Shared trip count of a tile's lane-block sweep and each block's
+    first chunk.
+
+    Every block sweeps ``trips = max_b n_b`` chunks.  A block whose window
+    ``[c_lo, c_lo + n)`` would then run past the slab starts lower, at
+    ``n_kc - trips``: it still covers its window, and it visits in-bounds
+    chunks, each once and with its true base row, so the extra chunks add
+    exact zeros and nothing is counted twice.
+    """
+    trips = functools.reduce(jnp.maximum, [n for _, n in windows])
+    return [jnp.minimum(c_lo, n_kc - trips) for c_lo, _ in windows], trips
+
+
+def block_offsets(f: jnp.ndarray, starts, bw: int):
+    """``f - base`` of each ``bw``-lane block at its first chunk.
+
+    A step ``k`` later the offset is ``t0 - 8k``: for every tap with
+    weight both subtractions are exact (``base <= f`` there), so the
+    weights are bit-identical to ``f - (base + 8k)``.
+    """
+    return [f[:, b * bw:(b + 1) * bw] - (c * ROWS).astype(jnp.float32)
+            for b, c in enumerate(starts)]
+
+
+def gather_blocks(f: jnp.ndarray, src_ref, windows, bw: int):
+    """:func:`gather_rows` with one window per ``bw``-lane block.
+
+    ``windows[b] = (c_lo, n)`` (:func:`z_window`) covers block ``b``'s
+    taps; one loop sweeps every block (:func:`block_starts`), so each step
+    works on the whole tile.  Returns one ``(8, bw)`` accumulator per
+    block.  Per lane the nonzero taps are summed in the same order, with
+    the same weights, as :func:`gather_rows` with any covering window.
+    """
+    starts, trips = block_starts(windows, src_ref.shape[0] // ROWS)
+    t0 = block_offsets(f, starts, bw)
+
+    def body(k, accs):
+        step = k * ROWS
+        out = []
+        for b, (c, acc) in enumerate(zip(starts, accs)):
+            rows = src_ref[pl.ds(pl.multiple_of(c * ROWS + step, ROWS), ROWS),
+                           b * bw:(b + 1) * bw]
+            t = t0[b] - step.astype(jnp.float32)
+            for r in range(ROWS):
+                acc = acc + hat(t - float(r)) * rows[r:r + 1, :]
+            out.append(acc)
+        return tuple(out)
+    zeros = tuple(jnp.zeros((ROWS, bw), jnp.float32) for _ in windows)
+    return jax.lax.fori_loop(0, trips, body, zeros)
+
+
+def scatter_blocks(f: jnp.ndarray, g: jnp.ndarray, dst_ref, windows,
+                   bw: int):
+    """Transpose of :func:`gather_blocks`: ``dst[k] += sum_i hat(f[i]-k)
+    g[i]`` over the same per-block windows.
+
+    The weights are bit-identical to :func:`gather_blocks`' (same
     ``(f - base) - r`` expression), so the pair is an exact adjoint.
     """
     local = iota_f32((ROWS, 1), 0)
+    starts, trips = block_starts(windows, dst_ref.shape[0] // ROWS)
+    t0 = block_offsets(f, starts, bw)
+    gb = [g[:, b * bw:(b + 1) * bw] for b in range(len(windows))]
 
-    def body(c, carry):
-        t0 = f - chunk_base(c, f.shape)
-        upd = jnp.zeros((ROWS, f.shape[1]), jnp.float32)
-        for i in range(ROWS):
-            upd = upd + hat(t0[i:i + 1, :] - local) * g[i:i + 1, :]
-        sl = pl.ds(pl.multiple_of(c * ROWS, ROWS), ROWS)
-        dst_ref[sl, :] += upd
+    def body(k, carry):
+        step = k * ROWS
+        for b, c in enumerate(starts):
+            t = t0[b] - step.astype(jnp.float32)
+            upd = jnp.zeros((ROWS, bw), jnp.float32)
+            for i in range(ROWS):
+                upd = upd + hat(t[i:i + 1, :] - local) * gb[b][i:i + 1, :]
+            sl = pl.ds(pl.multiple_of(c * ROWS + step, ROWS), ROWS)
+            dst_ref[sl, b * bw:(b + 1) * bw] += upd
         return carry
-    jax.lax.fori_loop(c_lo, c_hi, body, 0)
+    jax.lax.fori_loop(0, trips, body, 0)
 
 
 class RayFrame(NamedTuple):
@@ -175,6 +281,11 @@ class RayFrame(NamedTuple):
     d_x: jnp.ndarray        # (1, Nu) ray direction x (pixel minus source)
     d_y: jnp.ndarray        # (1, Nu)
     inv_dx: jnp.ndarray     # (1, Nu) guarded 1 / d_x
+    inv_dx_edges: tuple     # scalar 1 / d_x at each lane block's edge columns
+
+
+def guarded_inv(d):
+    return 1.0 / jnp.where(jnp.abs(d) < 1e-9, 1e-9, d)
 
 
 def ray_frame(c_ref, a, geo: ConeGeometry) -> RayFrame:
@@ -189,17 +300,41 @@ def ray_frame(c_ref, a, geo: ConeGeometry) -> RayFrame:
     u = (iota_f32((1, nu), 1) - (nu - 1) / 2.0) * du + offu
     d_x = dcx + u * eux - sx
     d_y = dcy + u * euy - sy
-    inv_dx = 1.0 / jnp.where(jnp.abs(d_x) < 1e-9, 1e-9, d_x)
-    return RayFrame(sx, sy, sz, d_x, d_y, inv_dx)
+    edges = tuple(guarded_inv(dcx + ((col - (nu - 1) / 2.0) * du + offu)
+                              * eux - sx)
+                  for col in edge_columns(nu))
+    return RayFrame(sx, sy, sz, d_x, d_y, guarded_inv(d_x), edges)
+
+
+def edge_columns(nu: int):
+    """First and last detector column of every lane block, in order."""
+    bw = lane_block(nu)
+    return [c for b in range(0, nu, bw) for c in (b, b + bw - 1)]
+
+
+def edge_spans(fr: RayFrame, x):
+    """Ray parameters of the lane blocks' edge columns at plane ``x``,
+    clipped to ``[0, 1]``: ``((s_a, s_b), ...)`` per block (scalars)."""
+    s = [jnp.clip((x - fr.sx) * inv, 0.0, 1.0) for inv in fr.inv_dx_edges]
+    return tuple(zip(s[0::2], s[1::2]))
+
+
+def tile_windows(spans, za, t, sz, n_kc: int, geo: ConeGeometry):
+    """Per-lane-block z windows of detector-row tile ``t`` at one plane,
+    ``((c_lo, n), ...)``, from the plane's :func:`edge_spans` and the
+    source's slab-local z ``za`` (:func:`z_origin`): scalar work only."""
+    nv = geo.n_detector[0]
+    rows = (t * ROWS, jnp.minimum(t * ROWS + ROWS - 1, nv - 1))
+    d0, d1 = (z_slope(r.astype(jnp.float32), sz, geo) for r in rows)
+    return tuple(z_window(za, s_a, s_b, d0, d1, n_kc) for s_a, s_b in spans)
 
 
 def ray_rows(fr: RayFrame, t, geo: ConeGeometry):
-    """Detector-row tile ``t``: ray z directions (8, 1), in-range mask."""
+    """Detector-row tile ``t``: ray z directions (8, 1)."""
     nv = geo.n_detector[0]
     dv, offv = geo.d_detector[0], geo.off_detector[0]
     iv = iota_f32((ROWS, 1), 0, t * ROWS)
-    d_z = (iv - (nv - 1) / 2.0) * dv + offv - fr.sz
-    return d_z, iv < nv
+    return (iv - (nv - 1) / 2.0) * dv + offv - fr.sz
 
 
 def ray_seg(fr: RayFrame, d_z, geo: ConeGeometry) -> jnp.ndarray:
@@ -254,6 +389,7 @@ def _fp_kernel(c_ref, xc_ref, z0_ref, vol_ref, out_ref, colz_ref, seg_ref,
     z0 = z0_ref[0]
     n_kc = colz_ref.shape[0] // ROWS
     n_vt = seg_ref.shape[0] // ROWS
+    bw = lane_block(out_ref.shape[2])
 
     @pl.when(s_idx == 0)
     def _init():
@@ -261,26 +397,30 @@ def _fp_kernel(c_ref, xc_ref, z0_ref, vol_ref, out_ref, colz_ref, seg_ref,
 
     def angle_body(a, carry):
         fr = ray_frame(c_ref, a_first + a, geo)
+        za = z_origin(fr.sz, z0, geo)
 
         def seg_body(t, c):
-            d_z, _ = ray_rows(fr, t, geo)
             seg_ref[pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS), :] = \
-                ray_seg(fr, d_z, geo)
+                ray_seg(fr, ray_rows(fr, t, geo), geo)
             return c
         jax.lax.fori_loop(0, n_vt, seg_body, 0)
 
         def plane_body(p, c):
-            s_par, valid, wy = ray_plane(fr, xc_ref[s_idx * px + p], geo)
+            x = xc_ref[s_idx * px + p]
+            s_par, valid, wy = ray_plane(fr, x, geo)
+            spans = edge_spans(fr, x)
             colz_ref[...] = jnp.dot(vol_ref[p], wy, precision=HIGHEST,
                                     preferred_element_type=jnp.float32)
 
             def tile_body(t, c2):
-                d_z, row_ok = ray_rows(fr, t, geo)
-                fk = ray_fk(fr, s_par, d_z, z0, geo)
-                c_lo, c_hi = chunk_window(fk, (valid > 0.0) & row_ok, n_kc)
-                acc = gather_rows(fk, colz_ref, c_lo, c_hi)
+                fk = ray_fk(fr, s_par, ray_rows(fr, t, geo), z0, geo)
+                wins = tile_windows(spans, za, t, fr.sz, n_kc, geo)
+                accs = gather_blocks(fk, colz_ref, wins, bw)
                 sl = pl.ds(pl.multiple_of(t * ROWS, ROWS), ROWS)
-                out_ref[a, sl, :] += acc * seg_ref[sl, :] * valid
+                for b, acc in enumerate(accs):
+                    cols = slice(b * bw, (b + 1) * bw)
+                    out_ref[a, sl, cols] += (acc * seg_ref[sl, cols]
+                                             * valid[:, cols])
                 return c2
             return jax.lax.fori_loop(0, n_vt, tile_body, c)
         return jax.lax.fori_loop(0, px, plane_body, carry)
@@ -293,6 +433,34 @@ def plane_centers(geo: ConeGeometry, n_planes: int) -> jnp.ndarray:
     nx = geo.n_voxel[2]
     return jnp.asarray((np.arange(n_planes) - (nx - 1) / 2.0)
                        * geo.d_voxel[2] + geo.off_origin[2], jnp.float32)
+
+
+def z_chunks_per_tile(geo: ConeGeometry, angles) -> float:
+    """Mean z chunks the ray kernels sweep per detector tile and plane.
+
+    The sweep's trip count, ``max`` over lane blocks of each block's
+    :func:`z_window`, on the full volume (``z0 = 0``), averaged over every
+    marching plane and detector tile of a strided subsample of at most 16
+    of ``angles``; y-dominant angles count as the x-dominant ones the
+    kernels run after the -90 deg rotation.
+    """
+    nz, _, nx = geo.n_voxel
+    nv, nu = geo.n_detector
+    du, offu = geo.d_detector[1], geo.off_detector[1]
+    a = np.asarray(angles, np.float32).reshape(-1)
+    a = a[::-(-a.size // 16)]
+    a = np.where(dominant_axis_mask(a), a, a - np.float32(np.pi / 2))
+    k = np.asarray(angle_constants(geo, a), np.float64)[:, None, None, None]
+    sx, sz, dcx, eux = k[..., 0], k[..., 2], k[..., 3], k[..., 5]
+    u = (np.asarray(edge_columns(nu)) - (nu - 1) / 2.0) * du + offu
+    x = np.asarray(plane_centers(geo, nx), np.float64)[:, None, None]
+    span = np.clip((x - sx) / (dcx + u * eux - sx), 0.0, 1.0)  # (A,P,1,E)
+    iv = np.arange(0, round_up(nv, ROWS), ROWS, dtype=np.float64)[:, None]
+    d0 = z_slope(iv, sz, geo)
+    d1 = z_slope(np.minimum(iv + ROWS - 1, nv - 1), sz, geo)
+    _, n = z_window(z_origin(sz, 0.0, geo), span[..., 0::2], span[..., 1::2],
+                    d0, d1, -(-nz // ROWS), xp=np)         # (A, P, T, B)
+    return float(n.max(axis=-1).mean())
 
 
 def padded_angle_constants(geo: ConeGeometry, angles, n_pad: int):

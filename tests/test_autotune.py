@@ -208,6 +208,20 @@ def test_backend_kernel_config_reports_blocks():
     assert get_backend("ref").kernel_config(GEO) == {}
 
 
+def test_kernel_config_reports_z_chunks_per_tile_for_its_angles():
+    from repro.core.geometry import circular_angles
+    from repro.core.operator import CTOperator
+    from repro.kernels.fp_ray import z_chunks_per_tile
+    angles = circular_angles(12)
+    want = z_chunks_per_tile(GEO, angles)
+    bk = get_backend("pallas")
+    assert "z_chunks_per_tile" not in bk.kernel_config(GEO, planes=16)
+    got = bk.kernel_config(GEO, planes=16, angles=angles)
+    assert got["z_chunks_per_tile"] == want > 0
+    op = CTOperator(GEO, angles, backend="pallas")
+    assert op.kernel_config()["z_chunks_per_tile"] == want
+
+
 def test_backend_uses_tuned_blocks_and_distinct_dispatch_keys(fake_measure):
     """Tuned blocks flow into the dispatch key: the same geometry tuned
     to a different slab width must compile a distinct entry."""

@@ -4,6 +4,8 @@ Every Pallas kernel is validated over a sweep of shapes and dtypes; the
 fp/bp kernels also over geometry variations.
 """
 
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -13,9 +15,15 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.geometry import ConeGeometry, circular_angles, \
     dominant_axis_mask
+from repro.core.projector import forward_project_joseph
 from repro.kernels import ref
+from repro.kernels.bp_matched import bp_matched_pallas
 from repro.kernels.bp_voxel import bp_voxel_pallas
-from repro.kernels.fp_ray import fp_ray_pallas
+from repro.kernels.fp_ray import (ROWS, angle_constants, edge_spans,
+                                  fp_ray_pallas, lane_block, plane_centers,
+                                  ray_fk, ray_frame, ray_plane, ray_rows,
+                                  round_up, tile_windows, z_chunks_per_tile,
+                                  z_origin)
 from repro.kernels.tv_grad import tv_grad_pallas
 from repro.kernels.flash_attention import flash_attention
 
@@ -128,3 +136,183 @@ def test_fp_slab_split_matches_kernel(seed, n_splits):
     got = fp_ray_pallas(vol, geo, ax, slab_planes=slab, interpret=True)
     want = ref.fp_ray_ref(vol, geo, ax)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-3)
+
+
+# --------------------------------------------------------------------------
+# ray kernels: per-lane-block z windows from the scalar geometry
+# --------------------------------------------------------------------------
+
+def _replay_windows(geo, angles, z0=0, nz_slab=None, planes=None):
+    """The ray kernels' per-ray z rows and per-block windows at every
+    (angle, plane, detector tile), replayed with the kernels' own helpers
+    outside Pallas: fk (A, P, T, 8, Nu), valid (A, P, T, 1, Nu) and the
+    windows' c_lo, n (A, P, T, blocks)."""
+    n_rows = round_up(geo.n_voxel[0] if nz_slab is None else nz_slab, ROWS)
+    consts = angle_constants(geo, angles).reshape(-1)
+    xs = plane_centers(geo, geo.n_voxel[2]) if planes is None else planes
+    n_vt = round_up(geo.n_detector[0], ROWS) // ROWS
+
+    def one(a, x, t):
+        fr = ray_frame(consts, a, geo)
+        s_par, valid, _ = ray_plane(fr, x, geo)
+        fk = ray_fk(fr, s_par, ray_rows(fr, t, geo), z0, geo)
+        wins = tile_windows(edge_spans(fr, x), z_origin(fr.sz, z0, geo), t,
+                            fr.sz, n_rows // ROWS, geo)
+        return (fk, valid, jnp.stack([c for c, _ in wins]),
+                jnp.stack([n for _, n in wins]))
+    f = jax.vmap(jax.vmap(jax.vmap(one, (None, None, 0)), (None, 0, None)),
+                 (0, None, None))
+    out = jax.jit(f)(jnp.arange(len(angles)), jnp.asarray(xs),
+                     jnp.arange(n_vt))
+    return [np.asarray(o) for o in out]
+
+
+def _tile_mask(geo, fk, valid):
+    """Rays the kernels count: forward (valid) and on the detector."""
+    nv = geo.n_detector[0]
+    row = np.arange(fk.shape[2])[:, None] * ROWS + np.arange(ROWS)
+    return (valid > 0) & (row < nv)[:, :, None]
+
+
+def _old_rule_chunks(fk, mask, n_rows):
+    """Chunks per tile under one window per tile from the masked min/max
+    of fk (the rule ``bp_voxel`` keeps, ``chunk_window``)."""
+    lo = np.where(mask, fk, np.inf).min(axis=(-2, -1))
+    hi = np.where(mask, fk, -np.inf).max(axis=(-2, -1))
+    lim = n_rows + 2 * ROWS
+    c_lo = np.clip(np.floor(np.clip(lo, -lim, lim)), 0, n_rows) // ROWS
+    c_hi = (np.clip(np.floor(np.clip(hi, -lim, lim)) + 2, 0, n_rows)
+            + ROWS - 1) // ROWS
+    return c_hi - c_lo
+
+
+def _assert_windows_cover_taps(geo, angles, z0=0, nz_slab=None):
+    """Every tap with nonzero weight of every counted ray lies in its lane
+    block's window, and every window lies in the slab."""
+    n_rows = round_up(geo.n_voxel[0] if nz_slab is None else nz_slab, ROWS)
+    fk, valid, c_lo, n = _replay_windows(geo, angles, z0, nz_slab)
+    mask = _tile_mask(geo, fk, valid)
+    assert mask.any()
+    assert (n >= 0).all() and (c_lo + n <= n_rows // ROWS).all()
+    bw = lane_block(geo.n_detector[1])
+    block = np.arange(geo.n_detector[1]) // bw
+    lo = np.repeat(c_lo, bw, axis=-1)[..., None, :]     # per column
+    hi = lo + np.repeat(n, bw, axis=-1)[..., None, :]
+    taps = 0
+    for k in (np.floor(fk), np.floor(fk) + 1):
+        w = (np.maximum(0.0, 1.0 - np.abs(fk - k)) > 0) & mask \
+            & (k >= 0) & (k < n_rows)
+        chunk = k // ROWS
+        bad = w & ((chunk < lo) | (chunk >= hi))
+        assert not bad.any(), (
+            f"{bad.sum()} taps outside their block's window, e.g. at "
+            f"{np.argwhere(bad)[0]} (blocks {block.max() + 1})")
+        taps += w.sum()
+    assert taps > 0
+    return fk, mask, n
+
+
+def _xdom(angles):
+    return angles[np.nonzero(dominant_axis_mask(angles))[0]]
+
+
+def _ydom_rotated(angles):
+    # the kernels see a y-dominant angle as theta - 90 deg of the
+    # rotated scene (repro.core.backend)
+    return angles[np.nonzero(~dominant_axis_mask(angles))[0]] - np.pi / 2
+
+
+def _tall(geo):
+    """``geo`` with a square detector face: few rows that still span the
+    volume's height, so rays pass above and below every slab."""
+    return dataclasses.replace(geo, s_detector=(409.6, 409.6))
+
+
+_WIDE = _tall(ConeGeometry.nice(24, n_detector=(24, 256)))
+_COVER_CASES = {
+    "oblique": (_WIDE, _xdom(circular_angles(32)), 0, None),
+    "45deg": (_WIDE, np.float32([np.pi / 4, 3 * np.pi / 4,
+                                 -np.pi / 4, 5 * np.pi / 4]), 0, None),
+    "ydom_rotated": (_WIDE, _ydom_rotated(circular_angles(32)), 0, None),
+    "off_centre": (dataclasses.replace(
+        _tall(ConeGeometry.nice(24, n_detector=(20, 256))),
+        off_detector=(17.0, -23.0), off_origin=(9.0, 4.0, -6.0)),
+        _xdom(circular_angles(16)), 0, None),
+    "rect_tall": (ConeGeometry.nice(32, n_detector=(40, 128)),
+                  _xdom(circular_angles(16)), 0, None),
+    "rect_wide": (_tall(ConeGeometry.nice(24, n_detector=(16, 384))),
+                  _xdom(circular_angles(16)), 0, None),
+    "wide_fan": (ConeGeometry(n_voxel=(32, 16, 16), n_detector=(64, 256),
+                              s_detector=(409.6, 2000.0)),
+                 _xdom(circular_angles(16)), 0, None),
+    "one_block": (ConeGeometry.nice(24, n_detector=(24, 48)),
+                  _xdom(circular_angles(16)), 0, None),
+    "slab_mid": (_WIDE, _xdom(circular_angles(16)), 8, 8),
+    "slab_top": (_WIDE, _xdom(circular_angles(16)), 16, 8),
+    "slab_odd": (_WIDE, _xdom(circular_angles(16)), 5, 11),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_COVER_CASES))
+def test_z_windows_cover_every_tap(case):
+    geo, angles, z0, nz_slab = _COVER_CASES[case]
+    _assert_windows_cover_taps(geo, angles, z0, nz_slab)
+
+
+def test_z_chunks_per_tile_is_the_kernels_trip_count():
+    """The host counter reads the kernels' own rule: its mean equals the
+    replayed windows' mean trip count (max over lane blocks)."""
+    geo, angles = _WIDE, circular_angles(16)
+    rot = np.where(dominant_axis_mask(angles), angles, angles - np.pi / 2)
+    _, _, _, n = _replay_windows(geo, rot.astype(np.float32))
+    want = n.max(axis=-1).mean()
+    assert abs(z_chunks_per_tile(geo, angles) - want) < 1e-2 * want
+
+
+def test_z_chunks_per_tile_cbct512():
+    """At the benchmark's geometry the per-block windows sweep at most 3
+    chunks per tile on average, fewer than one window per tile."""
+    geo, angles = ConeGeometry.nice(512), circular_angles(512)
+    got = z_chunks_per_tile(geo, angles)
+    assert got <= 3.0
+    # the tile-wide rule, replayed on a subsample of angles and planes
+    sub = _xdom(angles)[::64]
+    xs = plane_centers(geo, 512)[::16]
+    fk, valid, _, n = _replay_windows(geo, sub, planes=xs)
+    old = _old_rule_chunks(fk, _tile_mask(geo, fk, valid), 512).mean()
+    assert n.max(axis=-1).mean() <= 3.0 < old
+
+
+_BLOCK_CASES = {
+    "full": (_tall(ConeGeometry.nice(16, n_detector=(16, 256))), 0, None),
+    # tiles at the slab's top edge where one block's window is cut short
+    # by the slab and the other block's is not: the short block's extra
+    # steps must add nothing
+    "slab": (_tall(ConeGeometry(n_voxel=(32, 16, 16), n_detector=(64, 256))),
+             8, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_ray_kernels_two_lane_blocks_parity_and_adjoint(case):
+    """A 256-column detector runs two lane blocks with their own windows:
+    FP and matched BP agree with the ref projector and its vjp, and the
+    pair is an exact adjoint."""
+    geo, z0, nz_slab = _BLOCK_CASES[case]
+    nz = geo.n_voxel[0] if nz_slab is None else nz_slab
+    ax = _xdom(circular_angles(16))
+    kv, kp = jax.random.split(jax.random.PRNGKey(7))
+    vol = jax.random.normal(kv, (nz,) + geo.n_voxel[1:], jnp.float32)
+    proj = jax.random.normal(kp, (len(ax),) + geo.n_detector, jnp.float32)
+
+    def fp_ref(v):
+        return forward_project_joseph(v, geo, jnp.asarray(ax), z0=z0)
+    got = fp_ray_pallas(vol, geo, ax, slab_planes=8, z0=z0)
+    np.testing.assert_allclose(got, fp_ref(vol), rtol=2e-4, atol=5e-3)
+    bp = bp_matched_pallas(proj, geo, ax, slab_planes=8, z0=z0,
+                           z_planes=nz)
+    _, vjp = jax.vjp(fp_ref, vol)
+    np.testing.assert_allclose(bp, vjp(proj)[0], rtol=2e-4, atol=5e-3)
+    lhs = np.vdot(np.asarray(got, np.float64), np.asarray(proj, np.float64))
+    rhs = np.vdot(np.asarray(vol, np.float64), np.asarray(bp, np.float64))
+    assert abs(lhs - rhs) < 1e-4 * max(abs(lhs), abs(rhs))

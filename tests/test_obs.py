@@ -446,6 +446,18 @@ def test_executor_phase_seconds_cover_step_wall_time(tracer):
     assert step_spans and all(s.attrs["device"] == 0 for s in step_spans)
 
 
+def test_executor_kernel_config_event_carries_z_chunks_per_tile(tracer):
+    """The job's kernel-config event reports the ray kernels' mean z
+    chunks per tile for the job's own geometry and angles."""
+    from repro.kernels.fp_ray import z_chunks_per_tile
+    from repro.serve.executor import JobExecutor
+    ex = JobExecutor(_job(backend="pallas"), mode="plain", memory=_mem(800))
+    ex.start()
+    (ev,) = tracer.events("kernel-config")
+    assert ev.attrs["z_chunks_per_tile"] == z_chunks_per_tile(GEO, ANGLES)
+    assert ev.attrs["z_chunks_per_tile"] > 0
+
+
 @pytest.mark.parametrize("mode,kib", [("plain", 800), ("stream", 24)])
 def test_executor_step_spans_its_wait_for_the_state(tracer, mode, kib):
     """The executor's own wait for a step's state opens a ``sync`` layer

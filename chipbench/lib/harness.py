@@ -2,20 +2,14 @@
 
 Everything a cell needs is found by name: ``BENCHMARK.json`` names the
 cell's configuration and traffic mix, ``configs/<config>.json`` holds the
-deployment (geometry, angles, execution mode, precision),
-``traffic/<traffic>.json`` the job mix, ``cells/<cell>.json`` the check's
-sample sizes and limits, ``metrics/<metric>.py`` one reader per per-layer
-metric and ``counts/<kernel>.py`` one work count per kernel.
-
-The window drives the program the way its users do:
-
-* ``mode: plain`` -- a ``ReconJob`` admitted by ``repro.serve.Scheduler``
-  (which must pick in-core execution from its own footprint model) and
-  stepped through ``Scheduler.claim_step`` / ``JobExecutor.step`` /
-  ``Scheduler.finish_step``, the loop ``repro.launch.recon`` runs;
-* ``mode: dist`` -- the step-wise algorithm over
-  ``CTOperator(mode="dist")`` on ``make_host_mesh(model_axis=1)``, the path
-  ``recon --mode dist`` runs.
+deployment (geometry, angles, execution ``mode``, precision),
+``traffic/<traffic>.json`` the job mix (its ``algorithm``),
+``cells/<cell>.json`` the check's sample sizes and limits,
+``targets/<mode>.py`` how the window drives the program in that mode,
+``algorithms/<algorithm>.py`` what the harness knows of an algorithm (the
+state to wait on, the warm pass, the host copy and the check),
+``metrics/<metric>.py`` one reader per per-layer metric and
+``counts/<kernel>.py`` one work count per kernel.
 
 Set-up is data, operator build, compilation, the algorithm's ``init`` and
 one warm pass of the update arithmetic; the window then runs whole
@@ -25,7 +19,6 @@ requested seconds (:func:`run_window`).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import importlib.util
 import json
@@ -77,6 +70,37 @@ def _reports(metric: dict, cell: str, e2e_names) -> bool:
     return metric.get("moves", metric["name"]) in e2e_names
 
 
+def _names(kind: str) -> List[str]:
+    d = os.path.join(BENCH, kind)
+    return sorted(f[:-3] for f in os.listdir(d) if f.endswith(".py"))
+
+
+_MODULES: Dict[str, object] = {}
+
+
+def _module(kind: str, name: str):
+    """``<kind>/<name>.py`` (``targets`` or ``algorithms``), loaded once."""
+    if name not in _names(kind):
+        raise KeyError(f"no {kind}/{name}.py (have {_names(kind)})")
+    key = f"{kind}/{name}"
+    if key not in _MODULES:
+        _MODULES[key] = load_module(os.path.join(BENCH, kind, name + ".py"),
+                                    f"chipbench_{kind}_{name}")
+    return _MODULES[key]
+
+
+def target(mode: str):
+    """The execution mode ``mode``: ``targets/<mode>.py``, whose
+    ``build(geo, angles, vol, cfg)`` returns the :class:`Target`."""
+    return _module("targets", mode)
+
+
+def algorithm(name: str):
+    """What the harness needs to know of one algorithm:
+    ``algorithms/<name>.py`` (see ``algorithms/cgls.py``)."""
+    return _module("algorithms", name)
+
+
 def find_cell(bench: dict, name: str) -> Cell:
     """The cell ``name`` of ``BENCHMARK.json`` with its files loaded."""
     wl = {w["name"]: w for w in bench["workloads"]}
@@ -87,6 +111,8 @@ def find_cell(bench: dict, name: str) -> Cell:
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = load_json(os.path.join(ROOT, cfg_entry["file"]))
     traffic = load_json(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+    target(config["mode"])
+    algorithm(traffic["algorithm"])
     params = load_json(os.path.join(BENCH, "cells", name + ".json"))
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
@@ -139,134 +165,30 @@ class CompileCounter:
 
 @dataclasses.dataclass
 class Target:
-    """The system under test as the window drives it: one step, and the
-    current recurrence state."""
+    """The system under test as the window drives it: one step, the
+    current algorithm state, and the devices it runs on."""
     step: Callable[[], None]
     state: Callable[[], object]
     b: object
     kernel_config: dict
     release: Callable[[], None]
+    devices: list
 
 
-def _block(state) -> None:
-    import jax
-    for leaf in jax.tree_util.tree_leaves(
-            [state.x, state.r, state.p, state.gamma]):
-        leaf.block_until_ready()
-
-
-class _EchoOperator:
+class EchoOperator:
     """Stands in for the operator in a warm pass of the update arithmetic:
-    returns arrays of the real operators' shapes and placements, so each
-    element-wise op of the step compiles before the window."""
+    ``fp(angles)`` and ``bp(angles)`` return arrays of the real operators'
+    shapes and placements, so each element-wise op of a step compiles
+    before the window."""
 
-    def __init__(self, st):
-        self._r, self._p = st.r, st.p
+    def __init__(self, fp, bp):
+        self._fp, self._bp = fp, bp
 
     def A(self, vol, angles=None):
-        return self._r
+        return self._fp(angles)
 
     def At(self, proj, angles=None, weight=None):
-        return self._p
-
-
-def warm_update(alg, st) -> None:
-    """Run the algorithm's step once on a copy of ``st`` with the operator
-    echoed back: compiles the update arithmetic, leaves ``st`` untouched
-    (the twin's arrays are copies, so a step may donate them)."""
-    import jax.numpy as jnp
-    twin = dataclasses.replace(st, op=_EchoOperator(st), x=jnp.copy(st.x),
-                               r=jnp.copy(st.r), p=jnp.copy(st.p))
-    _block(alg.step(twin))
-
-
-def build_plain(geo, angles, vol, cfg) -> Target:
-    import jax
-    import jax.numpy as jnp
-    from repro.core.backend import get_backend
-    from repro.core.geometry import dominant_axis_mask
-    from repro.core.splitting import MemoryModel
-    from repro.serve import JobStatus, ReconJob, Scheduler
-
-    fp = get_backend(cfg["backend"]).fp_mixed(geo, dominant_axis_mask(angles))
-    with annotate("chipbench.setup.project"):
-        b = fp(vol, jnp.asarray(angles))
-        b.block_until_ready()
-    log_peak("data projection")
-    sched = Scheduler(n_devices=1,
-                      memory=MemoryModel.from_device(jax.devices()[0]))
-    jid = sched.submit(ReconJob(cfg["algorithm"], geo, angles, b,
-                                n_iter=10 ** 9, backend=cfg["backend"]))
-    with annotate("chipbench.setup.admit"):
-        sched.admit()
-    log_peak("admission and init")
-    rec = sched.records[jid]
-    if rec.status is not JobStatus.RUNNING:
-        raise RuntimeError(f"job not admitted: {rec.status} {rec.error}")
-    if rec.streamed:
-        raise RuntimeError("the scheduler chose out-of-core execution; this "
-                           "cell measures in-core (plain) execution")
-    run = sched.running[jid]
-    executor, slot = run.executor, run.slot
-    with annotate("chipbench.setup.warm"):
-        warm_update(executor.alg, executor._state)
-    log_peak("warm pass")
-
-    def step():
-        claimed = sched.claim_step(slot)
-        t0 = time.monotonic()
-        with annotate("chipbench.step"):
-            claimed.executor.step()
-        sched.finish_step(claimed, time.monotonic() - t0)
-
-    def release():
-        executor.release()
-        sched.running.clear()
-
-    return Target(step, lambda: executor._state, b,
-                  executor._state.op.kernel_config(), release)
-
-
-def build_dist(geo, angles, vol, cfg) -> Target:
-    from repro.core.algorithms.stepwise import get_algorithm
-    from repro.core.operator import CTOperator
-    from repro.launch.mesh import make_host_mesh
-
-    mesh = make_host_mesh(model_axis=1)
-    alg = get_algorithm(cfg["algorithm"])
-    ctx = contextlib.ExitStack()
-    ctx.enter_context(mesh)
-    op = CTOperator(geo, angles, mode="dist", mesh=mesh,
-                    bp_weight=cfg["bp_weight"], backend=cfg["backend"])
-    with annotate("chipbench.setup.project"):
-        b = op.A(vol)
-        b.block_until_ready()
-    log_peak("data projection")
-    with annotate("chipbench.setup.init"):
-        holder = [alg.init(b, geo, angles, op=op)]
-        _block(holder[0])
-    log_peak("init")
-    with annotate("chipbench.setup.warm"):
-        # init projected x = 0, made on one device; the iterations project
-        # p, which lives on every chip of the mesh: a placement the forward
-        # operator compiles for separately
-        holder[0].op.A(holder[0].p).block_until_ready()
-        warm_update(alg, holder[0])
-    log_peak("warm pass")
-
-    def step():
-        with annotate("chipbench.step"):
-            holder[0] = alg.step(holder[0])
-            _block(holder[0])
-
-    def release():
-        holder.clear()
-        ctx.close()
-
-    return Target(step, lambda: holder[0], b, op.kernel_config(), release)
-
-
-TARGETS = {"plain": build_plain, "dist": build_dist}
+        return self._bp(angles)
 
 
 def annotate(name: str):
@@ -285,22 +207,13 @@ def log_peak(after: str) -> None:
     log(f"peak HBM after {after} (bytes, largest device): {max(hbm_peaks())}")
 
 
-def snapshot(state):
-    """The recurrence variables of ``state``, copied to the host: the check
-    then holds no device memory and no buffer the program may donate."""
-    import jax
-    from . import check as chk
-    x, r, p, g = jax.device_get([state.x, state.r, state.p, state.gamma])
-    return chk.Iterate(x, r, p, g, int(state.it))
-
-
-def run_window(target: Target, seconds: float):
+def run_window(target: Target, seconds: float, snapshot):
     """Whole iterations back to back until ``seconds`` have passed.
 
     Before the iteration expected to close the window (the time so far
     plus the last iteration's reaches ``seconds``) the state is copied to
-    the host for the check, with the clock and the ``chipbench.window``
-    span paused.  The window stops at the first iteration boundary at or
+    the host for the check (``snapshot(state)``), with the clock and the
+    ``chipbench.window`` span paused.  The window stops at the first iteration boundary at or
     after ``seconds`` whose iteration was copied; where a quicker iteration
     falls short, the copy is taken again before the next one.  Returns the
     copy, the iterations run and the window's seconds."""
@@ -379,8 +292,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                        s_detector=tuple(g["s_detector"]))
     rgeo = ref.Geometry.from_config(cfg)
     angles = ref.scan_angles(cfg["n_angles"])
-    devices = jax.devices()[:cell.chips] if cfg["mode"] == "plain" \
-        else jax.devices()
+    algo = algorithm(cfg["algorithm"])
     counter = CompileCounter()
     phases: Dict[str, float] = {}
 
@@ -393,12 +305,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         vol.block_until_ready()
     phase("data", t)
     t = time.perf_counter()
-    target = TARGETS[cfg["mode"]](geo, angles, vol, cfg)
+    tgt = target(cfg["mode"]).build(geo, angles, vol, cfg)
     del vol
     phase("build_project_init_warm", t)
-    log("blocks " + json.dumps({k: v for k, v in target.kernel_config.items()
+    log("blocks " + json.dumps({k: v for k, v in tgt.kernel_config.items()
                                 if "." in k}))
 
+    devices = tgt.devices
     setup_peak = hbm_peaks(devices)
     profiler, keep_trace = None, trace_dir is not None
     if trace:
@@ -406,16 +319,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         jax.profiler.start_trace(profiler)
     snap = counter.snapshot()
     setup_s = time.perf_counter() - t_start
-    before, n_iter, elapsed = run_window(target, seconds)
+    before, n_iter, elapsed = run_window(tgt, seconds, algo.snapshot)
     if profiler:
         jax.profiler.stop_trace()
     in_window = counter.since(snap)
-    s = target.state()
-    after = chk.Iterate(s.x, s.r, s.p, s.gamma, int(s.it))
-    del s
+    after = algo.current(tgt.state())
     peak = hbm_peaks(devices)
-    b = target.b
-    target.release()
+    b = tgt.b
+    tgt.release()
+    del tgt
     log(f"setup phases (s): {json.dumps(phases)}; setup_s {setup_s}")
     log(f"window: {n_iter} iterations in {elapsed} s")
     log(f"compile events inside the window: {json.dumps(in_window)}")
@@ -423,14 +335,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         + ", ".join(str(p) for p in setup_peak)
         + "; whole run: " + ", ".join(str(p) for p in peak))
 
-    # ---- correctness: the last iteration and the residual recurrence
+    # ---- correctness: the window's last iteration against the reference
     t = time.perf_counter()
-    sample = chk.sample_angles(seed, angles,
-                               cell.params["check"]["angles_per_dominance"],
-                               n_shards=len(devices))
-    box = chk.sample_box(seed, g["n_voxel"], cell.params["check"]["box"])
     with annotate("chipbench.check"):
-        c = chk.Check(before, after, b, angles, rgeo, sample, box)
+        c, sampled = algo.make_check(before, after, b, angles, rgeo, seed,
+                                     cfg, cell.params["check"],
+                                     len(devices))
         del before, after, b
         numbers = c.program_numbers()
         if on_numbers:
@@ -440,8 +350,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         del c
     limits = cell.params["limits"]
     correct = chk.verdict(numbers, limits)
-    log(f"check: angles {sample.tolist()}, box {box}, "
-        f"{time.perf_counter() - t:.1f} s")
+    log(f"check: {sampled}, {time.perf_counter() - t:.1f} s")
 
     dev0 = jax.devices()[0]
     device = {"platform": dev0.platform, "kind": dev0.device_kind,

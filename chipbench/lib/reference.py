@@ -26,7 +26,9 @@ which round their operands to bfloat16 (``"bf16"``) when asked.
 Only windows of the full operators are computed, which is what makes an
 fp32 reference affordable at 512^3: the forward projection of a whole
 volume at a handful of angles (:func:`fp_angles`), and the exact adjoint
-restricted to a box of voxel columns, over every angle (:func:`bp_box`).
+restricted to a box of voxel columns, over every angle (:func:`bp_box`);
+and TIGRE's voxel-driven pseudo-matched backprojection, which the SART
+family uses, on such a box (:func:`bp_voxel_box`).
 """
 
 from __future__ import annotations
@@ -236,6 +238,14 @@ def frame_angle(theta, xdom: bool):
     return t if xdom else t - jnp.float32(math.pi / 2.0)
 
 
+def fp_marching(vol, geo: Geometry, angles, xdom: bool, prec: str = "f32"):
+    """Forward projection at ``angles`` that all march x planes
+    (``xdom``) or all march y planes; ``angles`` may be traced."""
+    planes_t = _marching_planes(vol, xdom)
+    th = frame_angle(angles, xdom)
+    return jax.lax.map(lambda t: _fp_one(planes_t, geo, t, prec), th)
+
+
 def fp_angles(vol, geo: Geometry, angles: Sequence[float], prec: str = "f32"):
     """Forward projection of ``vol`` at ``angles``: ``(A, Nv, Nu)``."""
     angles = np.asarray(angles, np.float32)
@@ -245,9 +255,7 @@ def fp_angles(vol, geo: Geometry, angles: Sequence[float], prec: str = "f32"):
         idx = np.nonzero(xm == xdom)[0]
         if not idx.size:
             continue
-        planes_t = _marching_planes(vol, xdom)
-        th = frame_angle(jnp.asarray(angles[idx]), xdom)
-        proj = jax.lax.map(lambda t: _fp_one(planes_t, geo, t, prec), th)
+        proj = fp_marching(vol, geo, jnp.asarray(angles[idx]), xdom, prec)
         for n, a in enumerate(idx):
             out[a] = proj[n]
     return jnp.stack(out)
@@ -313,3 +321,51 @@ def bp_box(proj, geo: Geometry, angles, box, prec: str = "f32"):
             part = jnp.transpose(jnp.flip(part, axis=1), (0, 2, 1))
         total = total + part
     return total
+
+
+# --------------------------------------------------------------------------
+# voxel-driven backprojection on a box of voxel columns
+
+def bp_voxel_box(proj, geo: Geometry, angles, box, prec: str = "f32"):
+    """TIGRE's voxel-driven backprojection with the pseudo-matched weight,
+    on the voxel box ``[:, y0:y0+n, x0:x0+n]`` (``box = (y0, x0, n)``).
+
+    Each voxel centre is projected onto the detector from the source,
+    ``fu = (q * mag) / du + (Nu-1)/2`` and ``fv = (z * mag) / dv + (Nv-1)/2``
+    with ``mag = DSD / (DSO - p)``, ``p`` the voxel's depth along the
+    source axis and ``q`` its coordinate along ``e_u``; the projection is
+    sampled bilinearly there (zero off the detector) and weighted by
+    ``(DSD / (DSO - p))^2 * DSO / DSD``.  The bilinear sample is the
+    tent-weight sum, as in the forward projection: a dense matmul over
+    ``u`` and a dense weighted reduction over ``v``.  ``angles`` may be
+    traced, and so may the box's corner ``y0, x0`` (not its size ``n``).
+    Returns ``(Nz, n, n)`` indexed ``[k, y - y0, x - x0]``."""
+    y0, x0, n = box
+    nz, ny, nx = geo.n_voxel
+    dz, dy, dx = geo.d_voxel
+    nv, nu = geo.n_detector
+    dv, du = geo.d_detector
+    span = jnp.arange(n, dtype=jnp.float32)
+    xs = (span + x0 - (nx - 1) / 2.0) * dx
+    ys = (span + y0 - (ny - 1) / 2.0) * dy
+    zs = (jnp.arange(nz, dtype=jnp.float32) - (nz - 1) / 2.0) * dz
+    xx, yy = xs[None, :], ys[:, None]                       # (n, n) [y, x]
+    uu = jnp.arange(nu, dtype=jnp.float32)[:, None]
+
+    def one(acc, inp):
+        theta, p2d = inp
+        c, s = jnp.cos(theta), jnp.sin(theta)
+        p = (xx * c + yy * s).reshape(-1)                   # (n*n,)
+        q = (-xx * s + yy * c).reshape(-1)
+        depth = geo.DSO - p
+        mag = geo.DSD / depth
+        fu = (q * mag) / du + (nu - 1) / 2.0
+        fv = (zs[:, None] * mag[None, :]) / dv + (nv - 1) / 2.0   # (Nz, n*n)
+        w = (geo.DSD / depth) ** 2 * (geo.DSO / geo.DSD)
+        col = dot(p2d, hat(fu[None, :] - uu), prec)        # (Nv, n*n)
+        return acc + mul(_z_sample(fv, col, prec), w[None, :], prec), None
+
+    th = jnp.asarray(angles, jnp.float32).reshape(-1)
+    out, _ = jax.lax.scan(one, jnp.zeros((nz, n * n), jnp.float32),
+                          (th, jnp.asarray(proj, jnp.float32)))
+    return out.reshape(nz, n, n)

@@ -111,8 +111,12 @@ def union(intervals: Sequence[Tuple[float, float]]) -> List[Tuple[float, float]]
 
 
 def is_kernel_call(ev: Event) -> bool:
-    """A Pallas kernel launch: an XLA custom call to a Mosaic TPU kernel."""
-    return "tpu_custom_call" in ev.name
+    """A Pallas kernel launch: an XLA custom call to a Mosaic TPU kernel, or
+    a custom fusion XLA built around one (the kernel with the write of its
+    result into a larger array), which keeps the ``pallas_call`` as its op."""
+    return "tpu_custom_call" in ev.name or (
+        "hlo_category=custom fusion" in ev.text
+        and "/pallas_call" in ev.text)
 
 
 def matches(ev: Event, names: Sequence[str]) -> bool:

@@ -24,6 +24,14 @@ def test_fp_counts_at_512(cell):
     assert k["bp_matched"].counts(geo, angles) == (flops, nbytes)
 
 
+def test_bp_voxel_counts_at_512(cell):
+    geo, angles = cell
+    flops, nbytes = harness.kernel_table()["bp_voxel"].counts(geo, angles)
+    # 512^3 voxels x 512 angles x (4 taps x (mul + add) + weight x accumulate)
+    assert flops == 10 * 512 ** 4
+    assert nbytes == 4 * (512 ** 3 + 512 ** 3)
+
+
 def test_split_over_devices(cell):
     geo, angles = cell
     fp = harness.kernel_table()["fp_ray"]

@@ -54,6 +54,17 @@ def test_box_adjoint_matches_program_vjp(setup, box):
                full[:, y0:y0 + n, x0:x0 + n]) < 1e-5
 
 
+@pytest.mark.parametrize("box", [(3, 5, 4), (0, 0, 16), (10, 2, 4)])
+def test_voxel_box_matches_program_voxel_backprojection(setup, box):
+    from repro.core import projector
+    geo, rgeo, angles, _, proj = setup
+    full = projector.backproject_voxel(proj, geo, jnp.asarray(angles),
+                                       weight="pmatched")
+    y0, x0, n = box
+    assert rel(ref.bp_voxel_box(proj, rgeo, angles, box),
+               full[:, y0:y0 + n, x0:x0 + n]) < 1e-5
+
+
 def test_pair_is_adjoint(setup):
     _, rgeo, angles, vol, proj = setup
     ax = ref.fp_angles(vol, rgeo, angles)
@@ -63,11 +74,14 @@ def test_pair_is_adjoint(setup):
     assert abs(lhs - rhs) / abs(lhs) < 1e-5
 
 
-@pytest.mark.parametrize("which", ["fp_angles", "bp_box"])
+@pytest.mark.parametrize("which", ["fp_angles", "bp_box", "bp_voxel_box"])
 def test_bf16_departs_from_f32(setup, which):
     _, rgeo, angles, vol, proj = setup
     if which == "fp_angles":
         run = lambda prec: ref.fp_angles(vol, rgeo, angles, prec)
-    else:
+    elif which == "bp_box":
         run = lambda prec: ref.bp_box(proj, rgeo, angles, (3, 5, 8), prec)
+    else:
+        run = lambda prec: ref.bp_voxel_box(proj, rgeo, angles, (3, 5, 8),
+                                            prec)
     assert 1e-4 < rel(run("bf16"), run("f32")) < 1e-1

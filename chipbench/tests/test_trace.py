@@ -69,3 +69,36 @@ def test_unknown_device_kind_is_an_error(reduced):
                         {"operator_applications_per_iteration": {"fp_ray": 1}})
     with pytest.raises(KeyError):
         ctx.roofline_share("fp_ray")
+
+
+def test_a_custom_fusion_around_a_kernel_counts_as_the_kernel():
+    """XLA may fuse a kernel launch with the write of its result into a
+    larger array (on a TPU trace of OS-SART at 512^3: ``%fp_ray.4 = ...
+    fusion(...), kind=kCustom``); the fusion keeps the ``pallas_call`` as
+    its op and is the kernel's time.  Other ops from the kernel's file are
+    not."""
+    src = "source=/x/src/repro/kernels/fp_ray.py:514"
+    call = "tf_op=jit(f)/repro.op.fp/jit(f)/fp_ray/pallas_call:"
+    ops = [
+        trace.Event('%fp_ray.1 = f32[66,512,512] custom-call(...), '
+                    'custom_call_target="tpu_custom_call"', 0, 400,
+                    f"fp_ray.1 {src} {call} hlo_category=custom-call",
+                    "fp_ray.1"),
+        trace.Event("%fp_ray.4 = f32[64,512,512] fusion(...), kind=kCustom, "
+                    "calls=%fused_computation.3", 400, 450,
+                    f"fp_ray.4 {src} {call} hlo_category=custom fusion",
+                    "fp_ray.4"),
+        trace.Event("%cosine_bitcast_fusion = fusion(...), kind=kLoop", 450,
+                    460, "cosine_bitcast_fusion source=/x/src/repro/kernels/"
+                    "fp_ray.py:70 tf_op=jit(f)/repro.op.fp/jit(f)/cos: "
+                    "hlo_category=loop fusion", "cosine_bitcast_fusion"),
+        trace.Event("%add.1 = add(...)", 460, 500,
+                    "add.1 tf_op=jit(add)/add: hlo_category=non-fusion "
+                    "elementwise", "add.1")]
+    tr = trace.Trace([trace.Event(trace.WINDOW, 0, 1000)], {0: ops})
+    assert [trace.is_kernel_call(e) for e in ops] == [True, True, False,
+                                                      False]
+    kernels = {k: m.TRACE_NAMES for k, m in harness.kernel_table().items()}
+    d = trace.reduce_trace(tr, kernels).devices[0]
+    assert d.kernel_ns["fp_ray"] == 450
+    assert d.other_ns == 50 and d.busy_ns == 500
